@@ -1,11 +1,12 @@
 """Shared benchmark utilities.
 
 Each benchmark prints ``name,us_per_call,derived`` CSV rows.
-``us_per_call`` is a real wall-clock measurement of the XLA-CPU reference
-path (interpret-mode Pallas timings are not meaningful); ``derived`` carries
-the modeled TPU-v5e number that reproduces the paper's table/figure
-(TFLOP/s, hit-rates, bandwidths) — this container has no TPU, so modeled
-numbers are the deliverable per the roofline methodology.
+``us_per_call`` is a wall-clock measurement of the XLA-CPU reference path
+(interpret-mode Pallas timings are not meaningful); ``derived`` carries
+numbers from the analytic TPU-v5e model (TFLOP/s, hit-rates, bandwidths).
+Neither is a device measurement: a modeled number is a hypothesis for a
+chip run to check, and a CPU time says nothing about the chip
+(``chip_smoke.py`` is the on-chip check).
 """
 from __future__ import annotations
 

@@ -1,8 +1,8 @@
 """Run every benchmark (one per paper table/figure).
 
 Prints ``name,us_per_call,derived`` CSV. us_per_call is the measured XLA-CPU
-reference path; derived carries the modeled TPU-v5e reproduction numbers
-(this container has no TPU — see DESIGN.md §7 / EXPERIMENTS.md §Roofline).
+reference path; derived carries numbers from the analytic TPU-v5e model —
+neither is a device measurement (see benchmarks/common.py).
 
 Each bench also writes a machine-readable ``BENCH_<key>.json`` (rows +
 parsed derived fields + a ``telemetry`` block from the launch journal;
@@ -16,6 +16,8 @@ from __future__ import annotations
 
 import sys
 import traceback
+
+from repro.util import enable_compile_cache
 
 from . import (bench_gemm, bench_attention_fwd, bench_attention_bwd,
                bench_attention_fusion, bench_calibration, bench_decode,
@@ -42,6 +44,7 @@ BENCHES = [
 
 
 def main() -> None:
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failed = []
     for name, key, fn in BENCHES:

@@ -24,6 +24,7 @@ See DESIGN.md §5 for where this sits in the policy resolution order.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Iterable, Optional
 
@@ -516,11 +517,32 @@ def active_pretuned() -> Optional[dict]:
     return _PRETUNED["table"]
 
 
+# The analytic ChipSpec of each TPU ``device_kind`` the model describes.
+CHIPS_BY_DEVICE_KIND = {"TPU v5 lite": pm.V5E}
+
+
 def active_chip() -> pm.ChipSpec:
     """The chip every ``chip=None`` ranking resolves against: the installed
-    table's fitted ChipSpec when present, else the analytic V5E defaults."""
+    table's fitted ChipSpec when present; else, on a TPU backend, the spec
+    of the attached chip's ``device_kind`` (a kind the model does not
+    describe raises — tiles ranked for another chip are no default); else
+    the analytic V5E defaults."""
     chip = _PRETUNED["chip"]
-    return chip if chip is not None else pm.V5E
+    return chip if chip is not None else _backend_chip()
+
+
+@functools.lru_cache(maxsize=None)
+def _backend_chip() -> pm.ChipSpec:
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return pm.V5E
+    kind = jax.devices()[0].device_kind
+    if kind not in CHIPS_BY_DEVICE_KIND:
+        raise ValueError(
+            f"no ChipSpec for TPU device_kind {kind!r}; "
+            f"have {sorted(CHIPS_BY_DEVICE_KIND)}")
+    return CHIPS_BY_DEVICE_KIND[kind]
 
 
 def chip_from_dict(d: dict) -> pm.ChipSpec:

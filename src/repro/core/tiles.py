@@ -143,11 +143,13 @@ def shape_ragged(rows_dim: int, minor_dim: int, dtype) -> bool:
     return rows_dim % sub != 0 or minor_dim % lane != 0
 
 
-def compiler_params(*, dimension_semantics: tuple):
-    """pltpu compiler params across jax versions (CompilerParams was named
-    TPUCompilerParams before jax 0.5)."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(dimension_semantics=dimension_semantics)
+def compiler_params(*, dimension_semantics: tuple | None = None):
+    """Mosaic compiler params for every pallas_call in the repo. The scoped
+    VMEM limit is the same ``VMEM_BYTES`` the legality checks budget
+    against, so a policy the autotuner calls legal is one the compiler
+    accepts (its default scoped limit is far lower)."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=VMEM_BYTES)
 
 
 def padded_tile_bytes(shape: Sequence[int], dtype) -> int:

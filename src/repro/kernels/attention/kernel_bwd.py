@@ -91,8 +91,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
+        lse = lse_ref[0, 0]
+        delta = delta_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         p, ds_factor = _p_and_dsfactor(s, lse, epilogue, q_start, kv_start,
@@ -135,8 +135,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
+        lse = lse_ref[0, 0]
+        delta = delta_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         p, ds_factor = _p_and_dsfactor(s, lse, epilogue, q_start, kv_start,
@@ -182,7 +182,10 @@ def _flash_bwd(q, k, v, out, lse, do, *, policy: KernelPolicy,
     policy.check()  # budget covers the larger of the two passes' scratch
 
     # delta = rowsum(dO * O): cheap, memory-bound; jnp preprocess (as in FA2/3)
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
+                    keepdims=True)
+    # lse and delta stream as (block_q, 1) columns (see kernel_fwd)
+    lse = lse.reshape(b, h, sq, 1)
 
     def tile(shape, index_map, dtype, *, ragged):
         return tiles.block_spec(shape, index_map, dtype,
@@ -193,7 +196,8 @@ def _flash_bwd(q, k, v, out, lse, do, *, policy: KernelPolicy,
     kv_spec = tile((1, 1, block_kv, d),
                    lambda b_, h_, iq, ik, g=group: (b_, h_ // g, ik, 0),
                    k.dtype, ragged=ragged_kv)
-    vec_spec = pl.BlockSpec((1, 1, block_q), lambda b_, h_, iq, ik: (b_, h_, iq))
+    vec_spec = pl.BlockSpec((1, 1, block_q, 1),
+                            lambda b_, h_, iq, ik: (b_, h_, iq, 0))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, nkv=nkv, block_q=block_q,
@@ -218,7 +222,8 @@ def _flash_bwd(q, k, v, out, lse, do, *, policy: KernelPolicy,
     kv_out_spec = tile((1, 1, block_kv, d),
                        lambda b_, h_, ik, iq: (b_, h_, ik, 0), k.dtype,
                        ragged=ragged_kv)
-    vec_spec2 = pl.BlockSpec((1, 1, block_q), lambda b_, h_, ik, iq: (b_, h_, iq))
+    vec_spec2 = pl.BlockSpec((1, 1, block_q, 1),
+                             lambda b_, h_, ik, iq: (b_, h_, iq, 0))
 
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, nq=nq, block_q=block_q,

@@ -58,18 +58,19 @@ def _split_partials(q, k, v, valid, scale, softcap: float = 0.0):
     q: (G, D) f32, k/v: (bkv, D), valid: (bkv,) bool — or (G, bkv) bool
     when rows carry different positions (multi-token verify queries).
     ``softcap``: tanh logit cap applied in-split (0 = off). Returns
-    unnormalized (o (G, D) f32, m (G,), l (G,)); a fully-masked split
-    yields (0, MASK_VALUE, 0) which the combine weights to zero.
+    unnormalized (o (G, D) f32, m (G, 1), l (G, 1)) — the stats as
+    columns, the layout the kernels store; a fully-masked split yields
+    (0, MASK_VALUE, 0) which the combine weights to zero.
     """
     s = jax.lax.dot_general(q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     s = cap_logits(s, softcap)
     vmask = valid if valid.ndim == 2 else valid[None, :]
     s = jnp.where(vmask, s, MASK_VALUE)
-    m = jnp.max(s, axis=1)
-    p = jnp.exp(s - m[:, None])
+    m = jnp.max(s, axis=1, keepdims=True)
+    p = jnp.exp(s - m)
     p = jnp.where(vmask, p, 0.0)
-    l = jnp.sum(p, axis=1)
+    l = jnp.sum(p, axis=1, keepdims=True)
     o = jax.lax.dot_general(p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     return o, m, l
@@ -167,19 +168,20 @@ def _decode_kernel_paged(page_table_ref, lengths_ref, q_ref, k_ref, v_ref,
 
 
 def _partial_specs(b, hkv, n_splits, g, d):
-    """(out_specs, out_shapes) of the per-split partials + stats."""
+    """(out_specs, out_shapes) of the per-split partials + stats. The
+    stats are (g, 1) columns: every block's trailing two dims equal the
+    array's, which the TPU lowering requires for blocks this small."""
     part_map = lambda b_, h_, j_, *_: (b_, h_, j_, 0, 0)
-    stat_map = lambda b_, h_, j_, *_: (b_, h_, j_, 0)
     out_specs = [
         tiles.block_spec((1, 1, 1, g, d), part_map, jnp.float32,
                          allow_ragged_minor=True),   # q rows = GQA group
-        pl.BlockSpec((1, 1, 1, g), stat_map),
-        pl.BlockSpec((1, 1, 1, g), stat_map),
+        pl.BlockSpec((1, 1, 1, g, 1), part_map),
+        pl.BlockSpec((1, 1, 1, g, 1), part_map),
     ]
     out_shapes = [
         jax.ShapeDtypeStruct((b, hkv, n_splits, g, d), jnp.float32),
-        jax.ShapeDtypeStruct((b, hkv, n_splits, g), jnp.float32),
-        jax.ShapeDtypeStruct((b, hkv, n_splits, g), jnp.float32),
+        jax.ShapeDtypeStruct((b, hkv, n_splits, g, 1), jnp.float32),
+        jax.ShapeDtypeStruct((b, hkv, n_splits, g, 1), jnp.float32),
     ]
     return out_specs, out_shapes
 
@@ -234,11 +236,13 @@ def flash_decode(q, k, v, lengths, *, policy: KernelPolicy,
             out_specs=out_specs,
         ),
         out_shape=out_shapes,
+        compiler_params=tiles.compiler_params(),
         interpret=interpret,
     )(lengths, q, k, v)
     if sinks is not None:
         sinks = jnp.asarray(sinks, jnp.float32).reshape(hkv, 1, g)
-    return combine_splits(o, m, l, sinks=sinks).astype(q.dtype)
+    return combine_splits(o, m[..., 0], l[..., 0],
+                          sinks=sinks).astype(q.dtype)
 
 
 @functools.partial(
@@ -299,8 +303,10 @@ def flash_decode_paged(q, k_pages, v_pages, page_table, lengths, *,
             out_specs=out_specs,
         ),
         out_shape=out_shapes,
+        compiler_params=tiles.compiler_params(),
         interpret=interpret,
     )(page_table, lengths, q, k_pages, v_pages)
     if sinks is not None:
         sinks = jnp.asarray(sinks, jnp.float32).reshape(hkv, 1, g)
-    return combine_splits(o, m, l, sinks=sinks).astype(q.dtype)
+    return combine_splits(o, m[..., 0], l[..., 0],
+                          sinks=sinks).astype(q.dtype)

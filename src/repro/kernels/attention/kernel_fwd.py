@@ -117,7 +117,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, nq: int, nkv: int, n_heads: int,
         # logsumexp residual for the backward pass (includes the sink mass,
         # which is what makes the saved-preact convention hold: the bwd
         # kernels need no sink operand at all)
-        l_ref[0, 0] = lse[:, 0]
+        l_ref[0, 0] = lse
 
 
 @functools.partial(
@@ -159,7 +159,7 @@ def _flash_fwd(q, k, v, sinks, *, policy: KernelPolicy, causal: bool,
 
     def lse_map(b_, i, ik):
         hh, iq = hq_coords(i)
-        return (b_, hh, iq)
+        return (b_, hh, iq, 0)
 
     kernel = functools.partial(
         _fwd_kernel, nq=nq, nkv=nkv, n_heads=h, block_q=block_q,
@@ -177,11 +177,13 @@ def _flash_fwd(q, k, v, sinks, *, policy: KernelPolicy, causal: bool,
     operands = [q, k, v]
     if epilogue.sink:
         assert sinks is not None, "sink epilogue needs a sinks operand"
-        # one f32 scalar per head, streamed per (head, q-block) grid cell
+        # one f32 scalar per head, streamed per (head, q-block) grid cell;
+        # the (1, 1) trailing dims equal the array's, as the TPU lowering
+        # requires of a block
         in_specs.append(pl.BlockSpec(
-            (1, 1), lambda b_, i, ik: (hq_coords(i)[0], 0)))
+            (None, 1, 1), lambda b_, i, ik: (hq_coords(i)[0], 0, 0)))
         operands.append(
-            jnp.asarray(sinks, jnp.float32).reshape(h, 1))
+            jnp.asarray(sinks, jnp.float32).reshape(h, 1, 1))
 
     grid = (b, h * nq, nkv)
     out, lse = pl.pallas_call(
@@ -191,11 +193,13 @@ def _flash_fwd(q, k, v, sinks, *, policy: KernelPolicy, causal: bool,
         out_specs=[
             tiles.block_spec((1, 1, block_q, d), q_map, q.dtype,
                              allow_ragged_minor=ragged_q),
-            pl.BlockSpec((1, 1, block_q), lse_map),
+            # lse as a (block_q, 1) column: a trailing dim of 1 equals the
+            # array's, which the TPU lowering accepts for a block
+            pl.BlockSpec((1, 1, block_q, 1), lse_map),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),      # acc (pinned, DESIGN §2)
@@ -206,7 +210,7 @@ def _flash_fwd(q, k, v, sinks, *, policy: KernelPolicy, causal: bool,
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(*operands)
-    return out, lse
+    return out, lse[..., 0]
 
 
 def flash_attention_fwd(q, k, v, *, policy: KernelPolicy | None = None,

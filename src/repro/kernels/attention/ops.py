@@ -31,6 +31,7 @@ from repro import obs
 from repro.core import autotune
 from repro.core.policy import (KernelPolicy, legacy_attention_blocks,
                                make_policy, resolve_policy)
+from repro.kernels.modes import interpret_for
 from .epilogue import AttnEpilogue
 from .kernel_fwd import flash_attention_fwd
 from .kernel_bwd import flash_attention_bwd
@@ -124,6 +125,7 @@ def attention(q, k, v, *, causal: bool = False, window: int | None = None,
     """
     epilogue = AttnEpilogue(softcap=float(softcap) if softcap else 0.0,
                             sink=sinks is not None)
+    interpret = interpret_for(mode)
     if mode == "reference":
         if k.shape[2] > _CHUNKED_THRESHOLD:
             return attention_ref_chunked(q, k, v, causal=causal,
@@ -159,6 +161,7 @@ def attention(q, k, v, *, causal: bool = False, window: int | None = None,
                 # modeled traffic favors the eager chain (never at real
                 # shapes — the flash chain strictly dominates — but the
                 # plan, not the call site, owns that decision)
+                obs.incr("fallback.eager.attention")
                 return attention_ref(q, k, v, causal=causal, window=window,
                                      logit_scale=logit_scale,
                                      softcap=softcap, sinks=sinks)
@@ -169,7 +172,7 @@ def attention(q, k, v, *, causal: bool = False, window: int | None = None,
         _, bwd_policy = resolve_attention_policies(
             q.shape, k.shape, q.dtype, causal=causal, epilogue=epilogue)
     return _flash(q, k, v, sinks, causal, window, policy, bwd_policy,
-                  logit_scale, epilogue, mode == "pallas_interpret")
+                  logit_scale, epilogue, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -231,6 +234,7 @@ def attention_decode(q, k, v, lengths, *, window: int | None = None,
     qg = q.reshape(b, hkv, group, d)
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32).reshape(-1),
                                (b,))
+    interpret = interpret_for(mode)
     if mode == "reference":
         out = decode_ref(qg, k, v, lengths, window=window,
                          logit_scale=logit_scale, softcap=softcap,
@@ -254,8 +258,7 @@ def attention_decode(q, k, v, lengths, *, window: int | None = None,
         out = flash_decode(qg, k, v, lengths, policy=policy, window=window,
                            logit_scale=logit_scale,
                            softcap=float(softcap) if softcap else 0.0,
-                           sinks=sinks,
-                           interpret=mode == "pallas_interpret")
+                           sinks=sinks, interpret=interpret)
     return out.reshape(b, h, 1, d)
 
 
@@ -290,6 +293,7 @@ def attention_decode_paged(q, k_pages, v_pages, page_table, lengths, *,
                                axis=1).reshape(-1)
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32).reshape(-1),
                                (b,))
+    interpret = interpret_for(mode)
     if mode == "reference":
         # function-level import: serve sits above kernels in the layering
         from repro.serve.kv_cache import gather_pages
@@ -319,6 +323,6 @@ def attention_decode_paged(q, k_pages, v_pages, page_table, lengths, *,
                                  logit_scale=logit_scale,
                                  softcap=float(softcap) if softcap else 0.0,
                                  sinks=sinks,
-                                 interpret=mode == "pallas_interpret",
+                                 interpret=interpret,
                                  q_tokens=t)
     return out.reshape(b, h, t, d)

@@ -16,6 +16,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro import obs
+from repro.core import tiles
 from repro.core.policy import KernelPolicy, resolve_policy
 
 
@@ -85,6 +86,7 @@ def _fused(x, residual, weight, bias, seed, *, policy: KernelPolicy,
         out_specs=[row_spec, row_spec],
         out_shape=[jax.ShapeDtypeStruct((rows, d), x.dtype),
                    jax.ShapeDtypeStruct((rows, d), x.dtype)],
+        compiler_params=tiles.compiler_params(),
         interpret=interpret,
     )(seed_arr, x, residual, weight.reshape(1, d), bias.reshape(1, d))
     return out, new_resid

@@ -137,6 +137,10 @@ def _gemm_bwd_da(a, b, g, extras, preacts, *, policy: KernelPolicy,
     def kcol_map(i, c):
         return (0, row_col(i)[1])
 
+    def part_map(i, c):
+        r, col = row_col(i)
+        return (r, 0, col)
+
     def ctr_map(i, c):
         return (0, c)
 
@@ -184,10 +188,13 @@ def _gemm_bwd_da(a, b, g, extras, preacts, *, policy: KernelPolicy,
     if not prologue.is_identity:
         for name in prologue.grad_names():
             if name in ("dgamma", "dbeta"):
-                # one partial row per (row block, col block); jnp sums them
-                out_specs.append(tiles.block_spec((1, bko), o_map, _F32,
+                # one partial row per (row block, col block); jnp sums them.
+                # A (1, K) middle dim keeps the block's last two dims equal
+                # to the array's, which the TPU lowering requires.
+                out_specs.append(tiles.block_spec((None, 1, bko), part_map,
+                                                  _F32,
                                                   allow_ragged_minor=True))
-                out_shape.append(jax.ShapeDtypeStruct((num_rows, k), _F32))
+                out_shape.append(jax.ShapeDtypeStruct((num_rows, 1, k), _F32))
             else:  # dmean / drstd: one (rows, 1) column, exact per row block
                 out_specs.append(tiles.block_spec((bm, 1), row_map, _F32,
                                                   allow_ragged_minor=True))

@@ -20,9 +20,11 @@ variants, matching the two Megatron TP collectives:
 
 Bitwise parity (the kernel's oracle contract): every panel GEMM runs a
 full-K policy (block_k == K), which makes each output element a single-tile
-dot — bitwise-equal to ``jnp.dot`` row panels regardless of how the rows
-are batched. The unfused gather-then-gemm path and the jnp oracle therefore
-match the ring *bitwise*, per rank, in every mode.
+dot. XLA's dot is not row-separable — the value of a row can depend on how
+many rows share the call — so the jnp paths (reference mode, the oracle)
+compute every product as the same ring-panel-shaped dots the ring runs.
+The unfused gather-then-gemm path and the jnp oracle therefore match the
+ring *bitwise*, per rank, in every mode.
 
 These functions run INSIDE shard_map (they use ``jax.lax.axis_index`` /
 ``ppermute``); :func:`gemm_collective_sharded` is the host-level wrapper
@@ -36,7 +38,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro import obs
 from repro.core import autotune
@@ -60,13 +62,21 @@ def _full_k_policy(m, n, k, dtype):
     return pinned
 
 
-def _panel_gemm(a, b, *, mode, out_dtype, policy):
+def _jnp_panels(a, b, rows, out_dtype):
+    """``a @ b`` as dots of ``rows``-row panels: the one dot shape every
+    jnp path shares with the ring, so their results agree bitwise."""
+    pet = jnp.float32 if out_dtype == jnp.float32 else None
+    return jnp.concatenate(
+        [jnp.dot(a[i:i + rows], b, preferred_element_type=pet)
+         for i in range(0, a.shape[0], rows)]).astype(out_dtype)
+
+
+def _panel_gemm(a, b, *, mode, out_dtype, policy, rows):
     """One panel launch: gemm_fused with the pinned policy, or the jnp
-    oracle in reference mode (identical values — that is the point)."""
+    oracle in reference mode (identical values — that is the point).
+    ``rows`` is the ring's panel height."""
     if mode == "reference":
-        return jnp.dot(a, b, preferred_element_type=jnp.float32
-                       if out_dtype == jnp.float32 else None
-                       ).astype(out_dtype)
+        return _jnp_panels(a, b, rows, out_dtype)
     from .ops import gemm_fused
     from .epilogue import EPILOGUE_NONE
 
@@ -95,7 +105,7 @@ def _ag_ring(x, w, *, axis_name, axis_size, mode, out_dtype, policy):
     for step in range(s_):
         origin = (rank - step) % s_
         y = _panel_gemm(chunk, w, mode=mode, out_dtype=out_dtype,
-                        policy=policy)
+                        policy=policy, rows=m_loc)
         out = jax.lax.dynamic_update_slice(out, y, (origin * m_loc, 0))
         if step < s_ - 1:
             chunk = jax.lax.ppermute(chunk, axis_name, _ring_perm(s_))
@@ -108,7 +118,8 @@ def _ag_gather_then_gemm(x, w, *, axis_name, axis_size, mode, out_dtype,
     full-K policy makes its row panels bitwise-equal to the ring's."""
     del axis_size
     ag = jax.lax.all_gather(x, axis_name, axis=0, tiled=True)
-    return _panel_gemm(ag, w, mode=mode, out_dtype=out_dtype, policy=policy)
+    return _panel_gemm(ag, w, mode=mode, out_dtype=out_dtype, policy=policy,
+                       rows=x.shape[0])
 
 
 # ---------------------------------------------------------------------------
@@ -131,7 +142,7 @@ def _rs_ring(x, w, *, axis_name, axis_size, mode, out_dtype, policy):
     for step in range(s_):
         p_idx = (rank - step - 1) % s_
         y = _panel_gemm(_rs_panel(x, p_idx, m_loc), w, mode=mode,
-                        out_dtype=jnp.float32, policy=policy)
+                        out_dtype=jnp.float32, policy=policy, rows=m_loc)
         if acc is None:
             acc = y
         else:
@@ -150,7 +161,7 @@ def _rs_gather_then_sum(x, w, *, axis_name, axis_size, mode, out_dtype,
     m_loc = m // s_
     rank = jax.lax.axis_index(axis_name)
     partial = _panel_gemm(x, w, mode=mode, out_dtype=jnp.float32,
-                          policy=policy)
+                          policy=policy, rows=m_loc)
     all_p = jax.lax.all_gather(partial, axis_name, axis=0)  # (S, M, N)
     acc = jnp.zeros((m_loc, w.shape[1]), jnp.float32)
     for i in range(s_):
@@ -214,16 +225,16 @@ def gemm_collective_oracle(x_full, w_full, *, variant: str, axis_size: int,
     the k_loc contributions in the ring's rank order (rank-dependent, so
     the oracle returns the (S, M/S, N) stack of per-rank panels)."""
     out_dtype = out_dtype or x_full.dtype
-    if variant == "all_gather":
-        return jnp.dot(x_full, w_full).astype(out_dtype)
     m, k = x_full.shape
     n = w_full.shape[1]
     s_ = axis_size
     m_loc, k_loc = m // s_, k // s_
+    if variant == "all_gather":
+        return _jnp_panels(x_full, w_full, m_loc, out_dtype)
     # per-source partial products, fp32
-    parts = [jnp.dot(x_full[:, src * k_loc:(src + 1) * k_loc],
-                     w_full[src * k_loc:(src + 1) * k_loc, :],
-                     preferred_element_type=jnp.float32)
+    parts = [_jnp_panels(x_full[:, src * k_loc:(src + 1) * k_loc],
+                         w_full[src * k_loc:(src + 1) * k_loc, :], m_loc,
+                         jnp.float32)
              for src in range(s_)]
     panels = []
     for rank in range(s_):
@@ -260,7 +271,7 @@ def gemm_collective_sharded(x, w, *, mesh, axis: str = "model",
         out_specs = P(axis, None)
 
     @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+                       out_specs=out_specs, check_vma=False)
     def inner(xl, wl):
         return gemm_collective(xl, wl, axis_name=axis, axis_size=s_,
                                variant=variant, mode=mode,

@@ -78,15 +78,23 @@ def rope_rotate(x, sin, cos, head_dim: int):
     sin/cos: (rows, head_dim) duplicated-halves tables (one row per token
     row of the tile). Identical math to kernels.rope.ref.rope_ref, applied
     per head_dim-sized column chunk.
+
+    Written with lane rolls and a lane mask, not a (rows, heads, head_dim)
+    reshape: Mosaic cannot split the lane dim into 64-wide heads.
     """
     rows, cols = x.shape
     half = head_dim // 2
-    xh = x.reshape(rows, cols // head_dim, head_dim)
-    x1 = xh[..., :half]
-    x2 = xh[..., half:]
-    rotated = jnp.concatenate([-x2, x1], axis=-1)
-    out = xh * cos[:, None, :] + rotated * sin[:, None, :]
-    return out.reshape(rows, cols)
+    reps = cols // head_dim
+    if reps > 1:
+        sin = jnp.concatenate([sin] * reps, axis=1)
+        cos = jnp.concatenate([cos] * reps, axis=1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1) % head_dim
+    # rotate-half within each head: lane j takes -x[j + half] in the first
+    # half of its head and x[j - half] in the second; the rolls' wrapped
+    # lanes are never selected
+    rotated = jnp.where(lane < half, -jnp.roll(x, -half, axis=1),
+                        jnp.roll(x, half, axis=1))
+    return x * cos + rotated * sin
 
 
 @dataclasses.dataclass(frozen=True)

@@ -32,6 +32,7 @@ from repro.core import autotune
 from repro.core.grid_swizzle import SwizzleConfig, ROW_MAJOR, best_window
 from repro.core.policy import KernelPolicy, make_policy
 from repro.core.schedule import Schedule
+from repro.kernels.modes import interpret_for
 from .epilogue import EPILOGUE_NONE, Epilogue
 from .prologue import PROLOGUE_NONE, Prologue
 from .kernel import _fit_block, _gemm_pallas, gemm_pallas
@@ -78,6 +79,7 @@ def gemm(a, b, *, policy: KernelPolicy | None = None,
          schedule: Schedule | None = None,
          swizzle: SwizzleConfig | str | None = "auto",
          out_dtype=jnp.bfloat16, mode: str = "pallas_interpret"):
+    interpret = interpret_for(mode)
     if mode == "reference":
         return gemm_ref(a, b, out_dtype)
     m, k = a.shape
@@ -101,7 +103,7 @@ def gemm(a, b, *, policy: KernelPolicy | None = None,
                    dma_bytes=autotune.gemm_traffic_bytes(
                        policy, m, n, k, jnp.dtype(a.dtype).itemsize))
     return gemm_pallas(a, b, policy=policy, out_dtype=out_dtype,
-                       interpret=(mode == "pallas_interpret"))
+                       interpret=interpret)
 
 
 # Default backward path for gemm_fused (DESIGN.md §11): 'kernel' runs the
@@ -273,6 +275,7 @@ def gemm_fused(a, b, *, epilogue: Epilogue = EPILOGUE_NONE,
                 f"gemm_fused: operand {name!r} "
                 f"{'missing for' if name in pro_wanted else 'not accepted by'} "
                 f"prologue {prologue.describe()!r}")
+    interpret = interpret_for(mode)
     if mode == "reference":
         return gemm_fused_ref(a, b, epilogue=epilogue, prologue=prologue,
                               b2=b2, bias=bias, residual=residual,
@@ -332,7 +335,7 @@ def gemm_fused(a, b, *, epilogue: Epilogue = EPILOGUE_NONE,
                                             prologue=prologue)
     timing = obs.timing_enabled()
     t0 = time.perf_counter() if timing else 0.0
-    out = _gemm_fused(policy, out_dtype, mode == "pallas_interpret",
+    out = _gemm_fused(policy, out_dtype, interpret,
                       epilogue, prologue, bwd_mode, a, b, tuple(extras))
     if obs.enabled():
         wall = None

@@ -57,6 +57,7 @@ def _rope(x, sin, cos, *, policy: KernelPolicy, interpret: bool):
         in_specs=[x_spec, t_spec, t_spec],
         out_specs=x_spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=tiles.compiler_params(),
         interpret=interpret,
     )(x, sin, cos)
 

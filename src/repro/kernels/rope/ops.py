@@ -11,6 +11,7 @@ import functools
 import jax
 
 from repro.core.policy import KernelPolicy
+from repro.kernels.modes import interpret_for
 from .kernel import rope_pallas
 from .ref import rope_ref, rope_tables  # noqa: F401
 
@@ -35,6 +36,7 @@ _rope.defvjp(_rope_fwd, _rope_bwd)
 def rope(x, sin, cos, *, policy: KernelPolicy | None = None,
          mode: str = "pallas_interpret"):
     """Apply rotary embedding. x: (B, H, S, D); sin/cos: (S, D)."""
+    interpret = interpret_for(mode)
     if mode == "reference":
         return rope_ref(x, sin, cos)
-    return _rope(x, sin, cos, policy, mode == "pallas_interpret")
+    return _rope(x, sin, cos, policy, interpret)

@@ -1,8 +1,7 @@
 """Training launcher.
 
-CPU-scale runs execute for real (``--smoke`` reduced configs or the paper's
-llama-100m). Production-scale configs are launched with the same code path on
-a real TPU fleet; on this host use ``repro.launch.dryrun`` for those.
+Runs ``train_loop`` on whatever JAX finds: ``--kernels pallas_tpu`` on a
+TPU, ``reference`` or ``pallas_interpret`` (``--smoke`` sizes) on a CPU.
 
   PYTHONPATH=src python -m repro.launch.train --arch llama-100m \
       --steps 200 --batch 8 --seq 512 --ckpt-dir /tmp/ckpt
@@ -20,6 +19,8 @@ from repro.data.pipeline import DataConfig, DataIterator
 from repro.optim import AdamWConfig, cosine_schedule, wsd_schedule
 from repro.train import train_loop, FailureInjector, StragglerWatchdog
 from repro.launch.mesh import make_host_mesh
+from repro.kernels.modes import MODES
+from repro.util import enable_compile_cache
 
 
 def main() -> None:
@@ -36,8 +37,8 @@ def main() -> None:
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--grad-compress", action="store_true")
     ap.add_argument("--zero1", action="store_true")
-    ap.add_argument("--kernels", choices=["reference", "pallas_interpret"],
-                    default="reference")
+    ap.add_argument("--kernels", default="reference",
+                    choices=MODES)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--fail-at", type=int, nargs="*", default=[],
@@ -46,6 +47,7 @@ def main() -> None:
                     help="train data-parallel over all local devices")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     sched = (wsd_schedule if args.schedule == "wsd" else cosine_schedule)(
         args.lr, args.warmup, args.steps)

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core import autotune
+from repro.kernels.modes import check_mode
 from . import lm as _lm
 from . import encdec as _ed
 from . import vlm as _vlm
@@ -132,6 +133,12 @@ def build_model(cfg: ModelConfig, *, mode: Optional[str] = None, mesh=None,
 def _build_model(cfg: ModelConfig, *, mode: Optional[str] = None, mesh=None,
                  data_axes=("data",)) -> Model:
     mode = mode if mode is not None else "reference"
+    check_mode(mode)
+    if mode == "pallas_tpu" and mesh is not None and mesh.size > 1:
+        # the dense layers call the kernels under GSPMD, and Mosaic kernels
+        # cannot be partitioned automatically (only shard_map'd ones can)
+        raise ValueError("mode='pallas_tpu' needs a single-device mesh; "
+                         "Mosaic kernels cannot be partitioned by GSPMD")
     kw = dict(mode=mode, mesh=mesh, data_axes=data_axes)
 
     if cfg.family == "encdec":

@@ -149,6 +149,7 @@ def fused_project_qkv_rope(cfg, p, x, positions, mode, prenorm=None):
     if resolved is None:
         plan = autotune.select_fusion("qkv_rope", shape, str(x.dtype))
         if plan["plan"] != "fused":
+            obs.incr("fallback.eager.qkv_rope")
             return None
         if prenorm is not None:
             x = apply_prenorm(cfg, x, prenorm)  # standalone-norm fallback

@@ -54,9 +54,13 @@ def init_params(defs: Mapping[str, ParamDef], rng: jax.Array) -> dict:
             u = jax.random.uniform(key, d.shape, jnp.float32, 0.9, 0.999)
             flat[path] = jnp.log(u / (1 - u)).astype(dtype)
         else:
-            fan_in = d.shape[0] if len(d.shape) > 1 else d.shape[-1]
+            # the input dim of a matrix, also under a leading layer or
+            # expert axis: (L, d_in, d_out) has fan-in d_in, not L
+            fan_in = d.shape[-2] if len(d.shape) > 1 else d.shape[-1]
             std = d.scale / math.sqrt(max(1, fan_in))
-            flat[path] = (jax.random.normal(key, d.shape, jnp.float32) * std
+            # drawn in the param dtype, so a bf16 stack never holds an f32
+            # copy of itself
+            flat[path] = (jax.random.normal(key, d.shape, dtype) * std
                           ).astype(dtype)
     return nest(flat)
 
@@ -163,6 +167,7 @@ def resolve_norm_prologue(cfg, prenorm, *, kind, plan_shape, gemm_shape,
         policy = autotune.select_policy("gemm", gemm_shape, dtype,
                                         epilogue=epilogue, prologue=pro)
     except ValueError:
+        obs.incr(f"fallback.prologue_illegal.{kind}")
         return None
     kw = {"gamma": scale}
     if bias is not None:
@@ -226,6 +231,7 @@ def _mlp_fused(cfg, p, x, *, residual, residual_scale, mode, gated,
         plan = autotune.select_fusion("mlp", (tokens, d, f, gated),
                                       str(x.dtype), residual=has_res)
         if plan["plan"] != "fused":
+            obs.incr("fallback.eager.mlp")
             return None
         if prenorm is not None:
             x = apply_prenorm(cfg, x, prenorm)  # standalone-norm fallback

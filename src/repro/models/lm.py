@@ -181,16 +181,22 @@ def lm_forward(cfg, params, tokens, *, mode: str = "reference", mesh=None,
                data_axes=("data",), remat: bool = False,
                return_hidden: bool = False):
     """tokens: (B, S) int32 -> logits (B, S, V) fp32 (or hidden states)."""
-    params = cast_params(params, cfg.compute_dtype)
+    layout = _layout(cfg)
+    # a scanned layer stack is cast to the compute dtype one layer at a time
+    # inside the scan body, so it is never held in both dtypes at once
+    xs = _scan_params(cfg, params, layout) if layout[0] == "scan" else None
+    params = cast_params({k: v for k, v in params.items()
+                          if xs is None or not k.startswith("blocks")},
+                         cfg.compute_dtype)
     x = params["embed"][tokens].astype(cfg.compute_dtype) * cfg.emb_scale
     positions = jnp.arange(tokens.shape[1])
 
-    layout = _layout(cfg)
     if layout[0] == "scan":
         _, pattern, _ = layout
 
         def body(carry, group_params):
             h, aux = carry
+            group_params = cast_params(group_params, cfg.compute_dtype)
             for kind, layer_params in zip(pattern, group_params):
                 h, aux_l = block_forward(cfg, kind, layer_params,
                                          h, positions=positions, mode=mode,
@@ -202,8 +208,7 @@ def lm_forward(cfg, params, tokens, *, mode: str = "reference", mesh=None,
             body = _remat(cfg, body)
         from repro.util import scan_unroll
         (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                   _scan_params(cfg, params, layout),
-                                   unroll=scan_unroll())
+                                   xs, unroll=scan_unroll())
     else:
         aux = jnp.zeros((), jnp.float32)
         for i in range(cfg.num_layers):

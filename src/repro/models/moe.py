@@ -17,7 +17,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro import obs
 from .common import ParamDef, _act_name, act_fn, apply_prenorm
@@ -91,6 +91,7 @@ def _expert_ffn_fused(cfg, p, x, mode, shard=None):
     plan = autotune.select_fusion("mlp", (t, d, f, gated), str(x.dtype),
                                   residual=False, shard=shard)
     if plan["plan"] != "fused":
+        obs.incr("fallback.eager.moe")
         return None
     act = _act_name(cfg.mlp_act)
     up_ep = (Epilogue(activation=act, gate=True) if gated
@@ -247,7 +248,7 @@ def moe_ep(cfg, p, x, *, mesh, data_axes=("data",), model_axis="model",
     w_gate = p["w_gate"] if has_gate else p["w_in"]
 
     @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+                       out_specs=out_specs, check_vma=False)
     def inner(x, router, w_in, w_out, w_gate, *norm):
         ep = mesh.shape[model_axis]
         rank = jax.lax.axis_index(model_axis)
@@ -341,7 +342,7 @@ def moe_tp(cfg, p, x, *, mesh, data_axes=("data",), model_axis="model",
     w_gate = p["w_gate"] if has_gate else p["w_in"]
 
     @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+                       out_specs=out_specs, check_vma=False)
     def inner(x, router, w_in, w_out, w_gate, *norm):
         bl, s, d = x.shape
         t = x.reshape(-1, d)

@@ -20,7 +20,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -63,7 +63,7 @@ def compressed_psum(x: jax.Array, mesh, axis: str = "data") -> jax.Array:
     """
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=P(*([None] * x.ndim)),
-                       out_specs=P(*([None] * x.ndim)), check_rep=False)
+                       out_specs=P(*([None] * x.ndim)), check_vma=False)
     def inner(v):
         q, scale = _quant(v.astype(jnp.float32))
         # all participants must dequantize with a common scale: use the max

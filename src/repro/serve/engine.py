@@ -28,7 +28,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import warnings
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -332,7 +332,7 @@ class PagedEngine:
                  prefix_cache: bool = False,
                  chunk_tokens: Optional[int] = None,
                  draft_model=None, draft_params=None, spec_tokens: int = 0,
-                 pretuned=None):
+                 pretuned=None, logits_hook: Optional[Callable] = None):
         if pretuned is not None:
             # calibrated policy table (path or report dict), installed
             # before the first page-count bucket pins its split-KV policy
@@ -388,7 +388,11 @@ class PagedEngine:
                     "sampling (temperature=0.0) in this engine")
             if draft_model.cfg.vocab_size != model.cfg.vocab_size:
                 raise ValueError("draft and target must share a vocabulary")
+            if logits_hook is not None:
+                raise ValueError("logits_hook observes single-token steps; "
+                                 "speculative rounds emit several at once")
         self._spec = draft_model is not None
+        self.logits_hook = logits_hook
 
         self.cache = model.init_paged_cache(batch_slots, self.n_pages,
                                             page_size)
@@ -550,6 +554,8 @@ class PagedEngine:
         fold_in index for seeded requests, so the draw is invariant to
         batch composition, admission order, and recompute preemption.
         """
+        if self.logits_hook is not None:
+            self.logits_hook(req.uid, position, logits_row)
         t = self._effective_temperature(req)
         if t == 0.0:
             return int(jnp.argmax(logits_row))
@@ -788,12 +794,14 @@ class PagedEngine:
             greedy = None
             for slot in active:
                 rec = self.slots[slot]
+                pos = len(rec.req.prompt) + len(rec.generated)
                 if self._effective_temperature(rec.req) == 0.0:
                     if greedy is None:      # one batched argmax for all
                         greedy = np.asarray(jnp.argmax(logits, axis=-1))
                     sampled[slot] = int(greedy[slot])
+                    if self.logits_hook is not None:
+                        self.logits_hook(rec.req.uid, pos, logits[slot])
                 else:
-                    pos = len(rec.req.prompt) + len(rec.generated)
                     sampled[slot] = self._sample_slot(logits[slot], rec.req,
                                                       pos)
         self.tokens_generated += n_active

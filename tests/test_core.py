@@ -129,3 +129,36 @@ class TestPerfModel:
         r = pm.roofline(1e15, 1e12, 1e11, n_chips=256)
         assert r.compute_s > 0 and r.memory_s > 0 and r.collective_s > 0
         assert r.bound in ("compute", "memory", "collective")
+
+
+class TestCompileCache:
+    """The persistent compile cache: the caller's JAX_COMPILATION_CACHE_DIR
+    when set (and no other directory set in code), else the fixed
+    <checkout>/.jax_cache."""
+
+    @pytest.fixture
+    def jax_config(self):
+        import jax
+        names = ("jax_compilation_cache_dir",
+                 "jax_persistent_cache_min_compile_time_secs")
+        saved = {n: getattr(jax.config, n) for n in names}
+        yield jax.config
+        for n, v in saved.items():
+            jax.config.update(n, v)
+
+    def test_env_dir_wins(self, jax_config, monkeypatch, tmp_path):
+        from repro.util import enable_compile_cache
+        jax_config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax_config.jax_compilation_cache_dir is None
+
+    def test_fixed_checkout_dir(self, jax_config, monkeypatch):
+        import os
+        from repro.util import CHECKOUT, enable_compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = os.path.join(CHECKOUT, ".jax_cache")
+        assert enable_compile_cache() == path
+        assert enable_compile_cache() == path      # stable across calls
+        assert jax_config.jax_compilation_cache_dir == path
+        assert os.path.isfile(os.path.join(CHECKOUT, "chip_smoke.py"))

@@ -11,6 +11,8 @@ Coverage per the acceptance bar (DESIGN.md §8):
     leaving mid-generation, greedy continuity vs the fixed-batch engine,
     per-bucket policy pinning, LRU bucket caps.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -275,6 +277,30 @@ class TestPagedEngine:
             want = fixed.generate(r.prompt[None, :], r.max_new_tokens)
             np.testing.assert_array_equal(results[r.uid], want.tokens[0])
 
+    def test_logits_hook_sees_every_emitted_token(self):
+        """logits_hook gets, at each token's absolute position, the row the
+        greedy token is the argmax of; speculative engines refuse it."""
+        model, params, cfg = self._model()
+        seen = {}
+        eng = PagedEngine(model, params, batch_slots=2, page_size=8,
+                          max_pages_per_seq=4,
+                          logits_hook=lambda uid, pos, row: seen.__setitem__(
+                              (uid, pos), np.asarray(row)))
+        reqs = [Request(0, np.arange(1, 6, dtype=np.int32), 4),
+                Request(1, np.arange(2, 12, dtype=np.int32), 3)]
+        for r in reqs:
+            eng.submit(r)
+        results = eng.run()
+        assert len(seen) == sum(r.max_new_tokens for r in reqs)
+        for r in reqs:
+            for pos in range(len(r.prompt), len(results[r.uid])):
+                assert int(np.argmax(seen[(r.uid, pos)])) == \
+                    results[r.uid][pos]
+        with pytest.raises(ValueError, match="logits_hook"):
+            PagedEngine(model, params, draft_model=model,
+                        draft_params=params, spec_tokens=3,
+                        logits_hook=lambda *a: None)
+
     def test_decode_policies_pinned_per_bucket(self):
         model, params, cfg = self._model()
         eng = PagedEngine(model, params, batch_slots=2, page_size=8,
@@ -354,8 +380,12 @@ class TestPagedEngine:
 class TestKernelModeEndToEnd:
     def test_paged_engine_kernel_mode_matches_reference(self):
         """The full serve loop over the Pallas (interpret) decode kernel
-        produces the same greedy tokens as the einsum reference path."""
-        cfg = get_config("granite-8b", smoke=True)
+        produces the same greedy tokens as the einsum reference path.
+        float32 compute: greedy equality is only well posed where the two
+        paths agree far below the top-two logit margins; bf16 rounding
+        alone flips near ties."""
+        cfg = dataclasses.replace(get_config("granite-8b", smoke=True),
+                                  compute_dtype="float32")
         params = build_model(cfg, mode="reference").init(jax.random.PRNGKey(0))
         outs = {}
         for mode in ("reference", "pallas_interpret"):

@@ -69,7 +69,8 @@ from repro.models import build_model
 from repro.train import make_train_step, sharded_init
 from repro.optim import AdamWConfig, constant_schedule
 from repro.data.pipeline import DataConfig, DataIterator
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ('data', 'model'))
 cfg = get_config('qwen2-72b', smoke=True)
 model = build_model(cfg, mode='reference', mesh=mesh)
 state = sharded_init(model, jax.random.PRNGKey(0), mesh, zero1=True)
@@ -463,7 +464,8 @@ print('OK')
 
     def test_gemm_collective_ring_bitwise(self, subproc):
         """Ring == gather-then-gemm == jnp oracle, bitwise, both variants,
-        reference and pallas_interpret (acceptance gate)."""
+        reference and pallas_interpret (acceptance gate); and within
+        float32 rounding of one plain dot."""
         out = subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.kernels.gemm import (gemm_collective_sharded,
@@ -474,6 +476,7 @@ x = (jax.random.normal(jax.random.PRNGKey(0), (M, K)) * 0.1
      ).astype(jnp.float32)
 w = (jax.random.normal(jax.random.PRNGKey(1), (K, N)) * 0.1
      ).astype(jnp.float32)
+plain = np.asarray(jnp.dot(x, w, precision=jax.lax.Precision.HIGHEST))
 for variant in ('all_gather', 'reduce_scatter'):
     oracle = gemm_collective_oracle(x, w, variant=variant, axis_size=4)
     if variant == 'reduce_scatter':
@@ -485,6 +488,9 @@ for variant in ('all_gather', 'reduce_scatter'):
                                          mode=mode, plan='gather')
         assert jnp.array_equal(ring, gather), (variant, mode, 'ring!=gather')
         assert jnp.array_equal(ring, oracle), (variant, mode, 'ring!=oracle')
+        # independent of the ring's panel decomposition: one plain dot
+        np.testing.assert_allclose(np.asarray(ring).reshape(-1, N), plain,
+                                   rtol=1e-5, atol=1e-6)
         print(variant, mode, 'bitwise OK')
 print('OK')
 """, devices=4)
@@ -521,8 +527,9 @@ from repro.models import build_model
 from repro.train import train_loop
 from repro.optim import AdamWConfig, constant_schedule
 from repro.data.pipeline import DataConfig, DataIterator
+from repro.launch.mesh import make_mesh
 cfg = get_config('mixtral-8x7b', smoke=True)
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = make_mesh((2, 4), ('data', 'model'))
 model = build_model(cfg, mode='reference', mesh=mesh)
 dcfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=32, global_batch=2)
 it = DataIterator(dcfg, mesh=mesh)

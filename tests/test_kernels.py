@@ -882,3 +882,73 @@ class TestRope:
             return float(jnp.sum(qm * kn))
         assert abs(dot_at(5, 3) - dot_at(102, 100)) < 1e-4
         assert abs(dot_at(7, 7) - dot_at(0, 0)) < 1e-4
+
+
+class TestModes:
+    """A mode string other than reference / pallas_interpret / pallas_tpu
+    raises in every op; it never selects the compiled kernels silently."""
+
+    @pytest.mark.parametrize("op", ["gemm", "gemm_fused", "attention",
+                                    "attention_decode", "decode_paged",
+                                    "rope", "fused_norm"])
+    def test_unknown_mode_raises(self, op):
+        from repro.kernels.attention import (attention_decode,
+                                             attention_decode_paged)
+        a = jnp.ones((8, 128), jnp.float32)
+        q = jnp.ones((1, 2, 8, 128), jnp.float32)
+        sin, cos = rope_tables(jnp.arange(8), 128, 10000.0)
+        calls = {
+            "gemm": lambda m: gemm(a, a.T, mode=m),
+            "gemm_fused": lambda m: gemm_fused(a, a.T, mode=m),
+            "attention": lambda m: attention(q, q, q, mode=m),
+            "attention_decode": lambda m: attention_decode(
+                q[:, :, :1], q, q, 8, mode=m),
+            "decode_paged": lambda m: attention_decode_paged(
+                q[:, :, :1], q.reshape(2, 1, 8, 128), q.reshape(2, 1, 8, 128),
+                jnp.ones((1, 2), jnp.int32), 8, mode=m),
+            "rope": lambda m: rope(q, sin, cos, mode=m),
+            "fused_norm": lambda m: dropout_residual_layernorm(
+                a, a, a[0], a[0], mode=m),
+        }
+        with pytest.raises(ValueError, match="unknown kernel mode"):
+            calls[op]("tpu")
+
+    def test_build_model_checks_mode(self):
+        from repro.configs import get_config
+        from repro.models import build_model
+        cfg = get_config("granite-8b", smoke=True)
+        with pytest.raises(ValueError, match="unknown kernel mode"):
+            build_model(cfg, mode="pallas")
+        # GSPMD cannot partition Mosaic kernels
+        with pytest.raises(ValueError, match="single-device mesh"):
+            build_model(cfg, mode="pallas_tpu",
+                        mesh=types.SimpleNamespace(size=4))
+
+    def test_interpret_launches_counted(self):
+        from repro import obs
+        a = jnp.ones((128, 128), jnp.float32)
+        with obs.capture() as cap:
+            gemm(a, a, mode="reference")
+            gemm(a, a, mode="pallas_interpret", out_dtype=jnp.float32)
+        assert cap.counter("kernels.interpret_launch") == 1
+
+    def test_active_chip_from_device_kind(self, monkeypatch):
+        """On a TPU backend the ChipSpec comes from device_kind; a kind the
+        model does not describe raises instead of defaulting to v5e."""
+        from repro.core import perf_model as pm
+
+        def fake_tpu(kind):
+            monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+            monkeypatch.setattr(jax, "devices", lambda *a: [
+                types.SimpleNamespace(device_kind=kind)])
+            autotune._backend_chip.cache_clear()
+
+        try:
+            fake_tpu("TPU v5 lite")
+            assert autotune.active_chip() is pm.V5E
+            fake_tpu("TPU v9 imaginary")
+            with pytest.raises(ValueError, match="no ChipSpec"):
+                autotune.active_chip()
+        finally:
+            monkeypatch.undo()
+            autotune._backend_chip.cache_clear()
