@@ -1,0 +1,6 @@
+"""1 - device busy time / traced window, training."""
+from chipbench.readers import idle_share
+
+
+def read(run):
+    return idle_share(run)
