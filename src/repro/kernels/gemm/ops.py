@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import contextlib
 import functools
-import time
 import warnings
 
 import jax
@@ -333,15 +332,9 @@ def gemm_fused(a, b, *, epilogue: Epilogue = EPILOGUE_NONE,
         bwd_mode = autotune.select_bwd_mode(m, n, k, dtype=str(a.dtype),
                                             epilogue=epilogue,
                                             prologue=prologue)
-    timing = obs.timing_enabled()
-    t0 = time.perf_counter() if timing else 0.0
     out = _gemm_fused(policy, out_dtype, interpret,
                       epilogue, prologue, bwd_mode, a, b, tuple(extras))
     if obs.enabled():
-        wall = None
-        if timing:
-            jax.block_until_ready(out)
-            wall = time.perf_counter() - t0
         obs.launch("gemm_fused", variant=bwd_mode,
                    grid=(max(1, m // policy.block_m),
                          max(1, n // policy.block_n)),
@@ -349,6 +342,5 @@ def gemm_fused(a, b, *, epilogue: Epilogue = EPILOGUE_NONE,
                    chain=f"{prologue.describe()}|{epilogue.describe()}",
                    dma_bytes=autotune.gemm_traffic_bytes(
                        policy, m, n, k, jnp.dtype(a.dtype).itemsize),
-                   flops=(2 if epilogue.gate else 1) * 2 * m * n * k,
-                   wall_s=wall)
+                   flops=(2 if epilogue.gate else 1) * 2 * m * n * k)
     return out

@@ -24,10 +24,19 @@ Four record types share one Recorder:
 - ``LaunchEvent``  — one per kernel-entry Python call (trace/dispatch
   semantics: a jitted caller re-using its cache emits nothing, exactly
   like the old monkeypatch counters).
-- ``SpanEvent``    — begin/end wall-clock intervals (``obs.span``).
+- ``SpanEvent``    — host intervals on ``time.perf_counter()``: spans
+  (``obs.span``) nest on a per-thread stack, so each knows its ``parent``
+  and inherits its request id ``rid``; ``obs.interval`` records a request
+  phase whose start lay in an earlier call.
 - counters        — monotonic floats (``obs.incr``), exported flat.
 - ``PlanDecision`` — every ``select_policy``/``select_fusion`` verdict
   with the losing candidates and their modeled bytes.
+
+``capture(annotate=True)`` also opens a ``jax.profiler.TraceAnnotation``
+for each span, named ``repro.<span>`` and carrying ``rid`` as a stat, so
+that a profiler trace taken meanwhile holds the program's spans on the
+clock of the device ops: in an ``.xplane.pb`` the program's spans are the
+host events whose name starts with ``PROFILER_PREFIX``.
 
 Exporters emit Chrome-trace/Perfetto JSON (``traceEvents``) and a flat
 counters JSON; both are validated by ``tools/trace_check.py`` in CI.
@@ -43,8 +52,9 @@ from typing import Any
 
 __all__ = [
     "LaunchEvent", "SpanEvent", "PlanDecision", "Recorder",
-    "capture", "enabled", "timing_enabled", "launch", "incr", "span",
-    "plan_decision", "null_allocations", "reset_null_allocations",
+    "capture", "enabled", "launch", "incr", "span", "interval",
+    "plan_decision", "PROFILER_PREFIX", "null_allocations",
+    "reset_null_allocations",
     "export_chrome_trace", "export_counters", "chrome_trace_events",
 ]
 
@@ -57,8 +67,7 @@ __all__ = [
 class LaunchEvent:
     """One kernel-entry call. ``dma_bytes``/``flops`` are the analytic
     perf_model numbers the caller already had in hand (never recomputed
-    here); ``wall_s`` is only filled when the capture asked for timing
-    (the instrumentation site then blocks on the result)."""
+    here). Kernel time comes from a device trace, not from here."""
     op: str                       # journal op kind, e.g. "gemm_fused"
     variant: str = ""             # free-form: "da", "paged", "prenorm", ...
     grid: tuple | None = None
@@ -66,31 +75,19 @@ class LaunchEvent:
     chain: str | None = None      # chain-spec summary (epilogue/prologue)
     dma_bytes: int | None = None
     flops: int | None = None
-    wall_s: float | None = None
     ts: float = 0.0               # perf_counter seconds at record time
-
-    def to_json(self) -> dict:
-        d = {"op": self.op, "ts": self.ts}
-        for k in ("variant", "grid", "policy", "chain", "dma_bytes",
-                  "flops", "wall_s"):
-            v = getattr(self, k)
-            if v not in (None, ""):
-                d[k] = list(v) if k == "grid" else v
-        return d
 
 
 @dataclass
 class SpanEvent:
     name: str
     ts: float                     # begin, perf_counter seconds
-    dur: float                    # seconds
+    dur: float = 0.0              # seconds
+    # the span open around this one on its thread when it began
+    parent: SpanEvent | None = field(default=None, repr=False,
+                                     compare=False)
+    rid: int | None = None        # request id; None outside a request
     meta: dict | None = None
-
-    def to_json(self) -> dict:
-        d = {"name": self.name, "ts": self.ts, "dur": self.dur}
-        if self.meta:
-            d["meta"] = self.meta
-        return d
 
 
 @dataclass
@@ -124,8 +121,12 @@ class PlanDecision:
 class Recorder:
     """Accumulates events for one ``capture()`` window."""
 
-    def __init__(self, *, timing: bool = False):
-        self.timing = timing
+    def __init__(self, *, annotate: bool = False):
+        # the profiler sink: jax.profiler.TraceAnnotation, or None
+        self.sink = None
+        if annotate:
+            from jax.profiler import TraceAnnotation
+            self.sink = TraceAnnotation
         self.launches: list[LaunchEvent] = []
         self.spans: list[SpanEvent] = []
         self.counters: dict[str, float] = {}
@@ -177,28 +178,19 @@ class Recorder:
 class _State(threading.local):
     def __init__(self):
         self.stack: list[Recorder] = []
+        self.open: list[SpanEvent] = []     # spans open on this thread
 
 
 _STATE = _State()
 _LOCK = threading.Lock()
 _NULL_ALLOCS = 0          # bumped only if an event is built while disabled
-_EPOCH = time.perf_counter()
-
-
-def _now() -> float:
-    return time.perf_counter() - _EPOCH
+PROFILER_PREFIX = "repro."
+_now = time.perf_counter
 
 
 def enabled() -> bool:
     """True when at least one ``capture()`` window is active (this thread)."""
     return bool(_STATE.stack)
-
-
-def timing_enabled() -> bool:
-    """True when the innermost active capture asked for wall-clock timing
-    (instrumentation sites then ``block_until_ready`` and fill wall_s)."""
-    s = _STATE.stack
-    return bool(s) and s[-1].timing
 
 
 def null_allocations() -> int:
@@ -214,7 +206,7 @@ def reset_null_allocations() -> None:
         _NULL_ALLOCS = 0
 
 
-def _record_launch(ev: LaunchEvent) -> None:
+def _record(ev: LaunchEvent | SpanEvent) -> None:
     global _NULL_ALLOCS
     s = _STATE.stack
     if not s:                       # tripwire: caller skipped the guard
@@ -222,7 +214,8 @@ def _record_launch(ev: LaunchEvent) -> None:
             _NULL_ALLOCS += 1
         return
     for rec in s:
-        rec.launches.append(ev)
+        (rec.launches if isinstance(ev, LaunchEvent)
+         else rec.spans).append(ev)
 
 
 # ---------------------------------------------------------------------------
@@ -230,7 +223,7 @@ def _record_launch(ev: LaunchEvent) -> None:
 # ---------------------------------------------------------------------------
 
 def launch(op: str, *, variant: str = "", grid=None, policy=None,
-           chain=None, dma_bytes=None, flops=None, wall_s=None) -> None:
+           chain=None, dma_bytes=None, flops=None) -> None:
     """Journal one kernel-entry call. ``policy`` may be a KernelPolicy
     (its ``describe()`` runs lazily, only here) or an already-built dict."""
     if not _STATE.stack:
@@ -240,10 +233,9 @@ def launch(op: str, *, variant: str = "", grid=None, policy=None,
         policy = describe() if describe else {"policy": str(policy)}
     if grid is not None:
         grid = tuple(grid)
-    _record_launch(LaunchEvent(op=op, variant=variant, grid=grid,
-                               policy=policy, chain=chain,
-                               dma_bytes=dma_bytes, flops=flops,
-                               wall_s=wall_s, ts=_now()))
+    _record(LaunchEvent(op=op, variant=variant, grid=grid, policy=policy,
+                        chain=chain, dma_bytes=dma_bytes, flops=flops,
+                        ts=_now()))
 
 
 def incr(name: str, value: float = 1.0) -> None:
@@ -266,20 +258,45 @@ def gauge(name: str, value: float) -> None:
 
 
 @contextmanager
-def span(name: str, **meta):
-    """Wall-clock interval: ``with obs.span("prefill", seq=512): ...``.
-    Free when disabled — no timestamps are taken, no dict is built."""
-    if not _STATE.stack:
+def span(name: str, *, rid: int | None = None, **meta):
+    """Host interval: ``with obs.span("engine.sample", rid=7): ...``. Its
+    parent is the span open around it on this thread, whose ``rid`` it
+    inherits unless given one. Free when disabled: no timestamps are
+    taken, no event is built."""
+    st = _STATE
+    if not st.stack:
         yield
         return
-    t0 = _now()
+    parent = st.open[-1] if st.open else None
+    if rid is None and parent is not None:
+        rid = parent.rid
+    sink = next((r.sink for r in st.stack if r.sink is not None), None)
+    ann = None
+    if sink is not None:
+        ann = (sink(PROFILER_PREFIX + name) if rid is None
+               else sink(PROFILER_PREFIX + name, rid=rid))
+        ann.__enter__()
+    ev = SpanEvent(name, _now(), parent=parent, rid=rid, meta=meta or None)
+    st.open.append(ev)
     try:
         yield
     finally:
-        ev = SpanEvent(name=name, ts=t0, dur=_now() - t0,
-                       meta=meta or None)
-        for rec in _STATE.stack:
-            rec.spans.append(ev)
+        ev.dur = _now() - ev.ts
+        st.open.pop()
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        _record(ev)
+
+
+def interval(name: str, t0: float, t1: float, *, rid: int | None = None,
+             **meta) -> None:
+    """An interval with given ``perf_counter`` bounds, such as a request
+    phase that began in an earlier call. Kept in the recorders only: it
+    has no parent and reaches no profiler trace."""
+    s = _STATE.stack
+    if not s:
+        return
+    _record(SpanEvent(name, t0, t1 - t0, rid=rid, meta=meta or None))
 
 
 def plan_decision(kind: str, op: str, shape, dtype: str, chosen,
@@ -296,11 +313,12 @@ def plan_decision(kind: str, op: str, shape, dtype: str, chosen,
 
 
 @contextmanager
-def capture(*, timing: bool = False):
+def capture(*, annotate: bool = False):
     """Activate a fresh Recorder for the dynamic extent of the block and
     yield it. Nested captures each see every event recorded inside them
-    (events fan out to the whole stack)."""
-    rec = Recorder(timing=timing)
+    (events fan out to the whole stack). With ``annotate`` every span is
+    also a ``jax.profiler.TraceAnnotation`` (the module docstring)."""
+    rec = Recorder(annotate=annotate)
     _STATE.stack.append(rec)
     try:
         yield rec
@@ -319,9 +337,12 @@ _TID_SPAN = 2     # span track
 
 def chrome_trace_events(rec: Recorder) -> list[dict]:
     """Flatten a Recorder into Chrome-trace ``traceEvents`` (Perfetto
-    opens these directly). Launches are instant events ('i') unless they
-    carry wall time (then complete events 'X'); spans are 'X'; counters
-    land as one final 'C' sample per series."""
+    opens these directly), timed from the recorder's first event. Launches
+    are instant events ('i'); spans are 'X' with their ``rid`` in
+    ``args``; counters land as one final 'C' sample per series."""
+    stamps = ([e.ts for e in rec.launches] + [sp.ts for sp in rec.spans]
+              + [p.ts for p in rec.plans])
+    zero = min(stamps, default=0.0)
     events: list[dict] = []
     for e in rec.launches:
         args: dict[str, Any] = {}
@@ -333,21 +354,22 @@ def chrome_trace_events(rec: Recorder) -> list[dict]:
             args["grid"] = list(e.grid)
         if e.policy is not None:
             args["policy"] = e.policy
-        base = {"name": e.op, "cat": "launch", "pid": _PID,
-                "tid": _TID_LAUNCH, "ts": e.ts * 1e6, "args": args}
-        if e.wall_s is not None:
-            events.append({**base, "ph": "X", "dur": e.wall_s * 1e6})
-        else:
-            events.append({**base, "ph": "i", "s": "t"})
+        events.append({"name": e.op, "cat": "launch", "ph": "i", "s": "t",
+                       "pid": _PID, "tid": _TID_LAUNCH,
+                       "ts": (e.ts - zero) * 1e6, "args": args})
     for sp in rec.spans:
+        args = dict(sp.meta or {})
+        if sp.rid is not None:
+            args["rid"] = sp.rid
         events.append({"name": sp.name, "cat": "span", "ph": "X",
-                       "pid": _PID, "tid": _TID_SPAN, "ts": sp.ts * 1e6,
-                       "dur": sp.dur * 1e6, "args": sp.meta or {}})
+                       "pid": _PID, "tid": _TID_SPAN,
+                       "ts": (sp.ts - zero) * 1e6, "dur": sp.dur * 1e6,
+                       "args": args})
     t_end = max([e.ts for e in rec.launches]
-                + [sp.ts + sp.dur for sp in rec.spans] + [0.0])
+                + [sp.ts + sp.dur for sp in rec.spans] + [zero])
     for name, value in sorted(rec.counters.items()):
         events.append({"name": name, "cat": "counter", "ph": "C",
-                       "pid": _PID, "ts": t_end * 1e6,
+                       "pid": _PID, "ts": (t_end - zero) * 1e6,
                        "args": {"value": value}})
     return events
 

@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import time
 import warnings
 from typing import Callable, Optional
 
@@ -296,6 +297,15 @@ def _pow2(x: int) -> int:
     return 1 << max(0, (x - 1).bit_length())
 
 
+def _program(fn: Callable, name: str) -> Callable:
+    """``fn`` under a stable name: its jitted program is then the module
+    ``jit_<name>`` of a device trace, not ``jit__lambda``."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
 class PagedEngine:
     """Continuous batching: paged KV cache + one compiled decode step.
 
@@ -414,6 +424,9 @@ class PagedEngine:
         self.spec_emitted = 0
         self.spec_participations = 0    # (slot, round) pairs
         self.peak_pages_in_use = 0
+        # uid -> (start of its running phase, is it a preempted
+        # continuation's); kept only while a capture is active
+        self._phase_start: dict[int, tuple[float, bool]] = {}
         self.lru_stats = {"hits": 0, "misses": 0, "evictions": 0}
         # One LRU, one cap, many key kinds: (batch_slots, page_count) ->
         # decode, ("prefill", S) -> exact prefill, ("chunk", C) -> chunked/
@@ -442,6 +455,7 @@ class PagedEngine:
         from repro.kernels.attention import resolve_decode_policy
         model = self.draft_model if draft else self.model
         cfg = model.cfg
+        prefix = "draft_" if draft else ""
 
         def build():
             hkv = cfg.num_kv_heads
@@ -452,9 +466,8 @@ class PagedEngine:
             return {
                 "policies": {"attention_decode": policy},
                 "decode": jax.jit(
-                    lambda params, tok, cache, pt, lens:
-                        model.decode_step_paged(params, tok, cache, pt,
-                                                lens),
+                    _program(model.decode_step_paged,
+                             prefix + "decode_step_paged"),
                     donate_argnums=(2,)),   # pools are the dominant buffers
             }
         key = (("draft_decode", mp_bucket) if draft
@@ -464,6 +477,7 @@ class PagedEngine:
     def _prefill_bucket(self, padded_len: int, *, draft: bool = False
                         ) -> dict:
         model = self.draft_model if draft else self.model
+        prefix = "draft_" if draft else ""
 
         def build():
             return {
@@ -471,9 +485,7 @@ class PagedEngine:
                     model.cfg, batch=1, seq_len=padded_len,
                     decode_len=self.max_pages_per_seq * self.page_size),
                 "prefill": jax.jit(
-                    lambda params, toks, cache, rows, slot, n:
-                        model.prefill_paged(params, toks, cache, rows,
-                                            slot, n),
+                    _program(model.prefill_paged, prefix + "prefill_paged"),
                     donate_argnums=(2,)),
             }
         key = ("draft_prefill" if draft else "prefill", padded_len)
@@ -486,6 +498,7 @@ class PagedEngine:
         from repro.kernels.attention import resolve_decode_policy
         model = self.draft_model if draft else self.model
         cfg = model.cfg
+        prefix = "draft_" if draft else ""
 
         def build():
             hkv = cfg.num_kv_heads
@@ -497,9 +510,8 @@ class PagedEngine:
             return {
                 "policies": {"attention_decode": policy},
                 "chunk": jax.jit(
-                    lambda params, toks, cache, rows, start, last:
-                        model.prefill_paged_chunk(params, toks, cache, rows,
-                                                  start, last),
+                    _program(model.prefill_paged_chunk,
+                             prefix + "prefill_paged_chunk"),
                     donate_argnums=(2,)),
             }
         key = ("draft_chunk" if draft else "chunk", chunk_len)
@@ -519,9 +531,7 @@ class PagedEngine:
             return {
                 "policies": {"attention_decode": policy},
                 "verify": jax.jit(
-                    lambda params, toks, cache, pt, lens:
-                        model.decode_step_paged(params, toks, cache, pt,
-                                                lens),
+                    _program(model.decode_step_paged, "verify_step_paged"),
                     donate_argnums=(2,)),
             }
         return self._touch(("verify", mp_bucket), build)
@@ -543,6 +553,23 @@ class PagedEngine:
                 f"request {req.uid}: speculative decoding requires greedy "
                 "requests (temperature 0.0)")
         self.pending.append(req)
+        self._request_phase(req.uid, None)
+
+    def _request_phase(self, uid: int, ended: Optional[str], *,
+                       reopen: bool = True, preempted: bool = False) -> None:
+        """Telemetry of a request's phases (queue, prefill, decode): close
+        the running one as the interval ``engine.request.<ended>`` and open
+        the next now. A preempted continuation's phases carry
+        ``preempted=True``."""
+        if not obs.enabled():
+            return
+        now = time.perf_counter()
+        t0, flagged = self._phase_start.pop(uid, (None, False))
+        if ended is not None and t0 is not None:
+            obs.interval(f"engine.request.{ended}", t0, now, rid=uid,
+                         **({"preempted": True} if flagged else {}))
+        if reopen:
+            self._phase_start[uid] = (now, flagged or preempted)
 
     def _effective_temperature(self, req: Request) -> float:
         return self.temperature if req.temperature is None else req.temperature
@@ -598,6 +625,7 @@ class PagedEngine:
                         self.alloc.free(matched)    # drop this admission's
                     break                           # refs; wait for retire
             self.pending.popleft()
+            self._request_phase(req.uid, "queue")
             slot = free[0]
             pages = matched + self.alloc.alloc(n_new)
             matched_len = len(matched) * self.page_size
@@ -622,7 +650,7 @@ class PagedEngine:
                 self.state = kvc.assign_slot(self.state, slot, pages, plen)
                 toks = np.asarray(req.prompt, np.int32)[None, :]
                 entry = self._prefill_bucket(plen)
-                with obs.span("engine.prefill", uid=req.uid, prompt_len=plen):
+                with obs.span("engine.prefill", rid=req.uid, prompt_len=plen):
                     self.cache, logits = entry["prefill"](
                         self.params, jnp.asarray(toks), self.cache,
                         self.state["page_table"][slot], slot, plen)
@@ -632,7 +660,9 @@ class PagedEngine:
                         self.draft_params, jnp.asarray(toks),
                         self.draft_cache, self.state["page_table"][slot],
                         slot, plen)
-                first = self._sample_slot(logits[0], req, plen)
+                with obs.span("engine.sample", rid=req.uid):
+                    first = self._sample_slot(logits[0], req, plen)
+                self._request_phase(req.uid, "prefill")
                 self.slots[slot] = _Slot(req=req, n_pages=n,
                                          generated=[first], next_token=first,
                                          pages=pages)
@@ -667,7 +697,7 @@ class PagedEngine:
         toks[0, : end - start] = np.asarray(req.prompt[start:end], np.int32)
         last = (plen - 1 - start) if end == plen else 0
         entry = self._chunk_bucket(c)
-        with obs.span("engine.prefill_chunk", uid=req.uid, start=start,
+        with obs.span("engine.prefill_chunk", rid=req.uid, start=start,
                       chunk=c):
             self.cache, logits = entry["chunk"](
                 self.params, jnp.asarray(toks), self.cache,
@@ -685,7 +715,9 @@ class PagedEngine:
             min(end, plen))
         if end >= plen:
             rec.prefill_cursor = -1
-            first = self._sample_slot(logits[0], req, plen)
+            with obs.span("engine.sample", rid=req.uid):
+                first = self._sample_slot(logits[0], req, plen)
+            self._request_phase(req.uid, "prefill")
             rec.generated = [first]
             rec.next_token = first
             self.tokens_generated += 1
@@ -746,6 +778,7 @@ class PagedEngine:
             temperature=rec.req.temperature,
             seed=rec.req.seed)
         self.pending.appendleft(cont)
+        self._request_phase(rec.req.uid, "decode", preempted=True)
         self.preemptions += 1
         obs.incr("engine.preemptions")
         del self.slots[slot]
@@ -757,7 +790,18 @@ class PagedEngine:
         self.results[rec.req.uid] = np.concatenate(
             [np.asarray(rec.req.prompt, np.int32),
              np.asarray(gen, np.int32)])
+        self._request_phase(rec.req.uid, "decode", reopen=False)
         del self.slots[slot]
+
+    def _retire_finished(self) -> None:
+        """Retire every decode-ready slot that has all its tokens."""
+        done = [s for s, r in self.slots.items()
+                if not r.prefilling
+                and len(r.generated) >= r.req.max_new_tokens]
+        if done:
+            with obs.span("engine.retire"):
+                for slot in done:
+                    self._retire(slot, self.slots[slot])
 
     def _launch_views(self, active: list, mp_bucket: int):
         """(page_table, lengths, act) for a decode/verify launch. Mid-prefill
@@ -780,16 +824,17 @@ class PagedEngine:
     def _decode_one(self, active: list, mp_bucket: int) -> None:
         """One single-token decode step for every decode-ready slot."""
         entry = self._decode_bucket(mp_bucket)
-        pt, lens, act = self._launch_views(active, mp_bucket)
-        tokens = np.zeros((self.batch_slots, 1), np.int32)
-        for slot in active:
-            tokens[slot, 0] = self.slots[slot].next_token
         n_active = len(active)
-        with obs.span("engine.decode_step", active_slots=n_active,
+        with obs.span("engine.decode_launch", active_slots=n_active,
                       mp_bucket=mp_bucket):
+            pt, lens, act = self._launch_views(active, mp_bucket)
+            tokens = np.zeros((self.batch_slots, 1), np.int32)
+            for slot in active:
+                tokens[slot, 0] = self.slots[slot].next_token
             self.cache, logits = entry["decode"](
                 self.params, jnp.asarray(tokens), self.cache, pt, lens)
             self.state["lengths"] = self.state["lengths"] + act
+        with obs.span("engine.sample"):
             sampled = {}
             greedy = None
             for slot in active:
@@ -883,59 +928,70 @@ class PagedEngine:
         """Admit, advance mid-prefill slots by one chunk, decode one step
         (or one speculative round) for every decode-ready slot, retire
         finished. Returns False when there is nothing left to do."""
-        self._admit()
-        # chunk-interleaved prefill: one fixed-size chunk per slot per step
-        # bounds the decode stall at one chunk instead of one full prompt
-        for slot in sorted(self.slots):
-            rec = self.slots[slot]
-            if rec.prefilling:
-                self._advance_prefill(slot, rec)
-        # retire slots that completed at admission (max_new_tokens == 1)
-        for slot in [s for s, r in self.slots.items()
-                     if not r.prefilling
-                     and len(r.generated) >= r.req.max_new_tokens]:
-            self._retire(slot, self.slots[slot])
-        if not self.slots:
-            if self.pending:
+        with obs.span("engine.step"):
+            with obs.span("engine.admit"):
                 self._admit()
-                if not self.slots:
-                    raise RuntimeError(
-                        "paged engine stalled: pending requests but no "
-                        "admissible slot (page pool too small?)")
-                return True
-            return False
+            # chunk-interleaved prefill: one fixed-size chunk per slot per
+            # step bounds the decode stall at one chunk instead of one full
+            # prompt
+            for slot in sorted(self.slots):
+                rec = self.slots[slot]
+                if rec.prefilling:
+                    self._advance_prefill(slot, rec)
+            # slots that completed at admission (max_new_tokens == 1)
+            self._retire_finished()
+            if not self.slots:
+                if self.pending:
+                    with obs.span("engine.admit"):
+                        self._admit()
+                    if not self.slots:
+                        raise RuntimeError(
+                            "paged engine stalled: pending requests but no "
+                            "admissible slot (page pool too small?)")
+                    return True
+                return False
 
-        # page growth; on pool exhaustion preempt the youngest stalled slot
-        # (freeing its pages) until the survivors fit. A lone slot never
-        # stalls: submit() bounds any single sequence to the pool size.
-        ahead = self.spec_tokens if self._spec else 1
-        stalled = self._try_grow(ahead)
-        while stalled:
-            self._preempt(stalled[-1])
-            stalled = self._try_grow(ahead)
-        if not self.slots:
-            return bool(self.pending)   # everything preempted; re-admit next
-        active = [s for s, r in sorted(self.slots.items())
-                  if not r.prefilling]
-        if not active:
+            # page growth; on pool exhaustion preempt the youngest stalled
+            # slot (freeing its pages) until the survivors fit. A lone slot
+            # never stalls: submit() bounds any single sequence to the pool.
+            ahead = self.spec_tokens if self._spec else 1
+            with obs.span("engine.grow"):
+                stalled = self._try_grow(ahead)
+                while stalled:
+                    self._preempt(stalled[-1])
+                    stalled = self._try_grow(ahead)
+            if not self.slots:
+                return bool(self.pending)   # all preempted; re-admit next
+            active = [s for s, r in sorted(self.slots.items())
+                      if not r.prefilling]
+            if not active:
+                self.steps += 1
+                return True             # all slots mid-prefill; decode next
+            max_pages = max(self.slots[s].n_pages for s in active)
+            mp_bucket = min(self.max_pages_per_seq, _pow2(max_pages))
+            self._note_occupancy()
+            self._count_decode_step()
+            if self._spec:
+                self._spec_round(active, mp_bucket)
+            else:
+                self._decode_one(active, mp_bucket)
             self.steps += 1
-            return True                 # all slots mid-prefill; decode next
-        max_pages = max(self.slots[s].n_pages for s in active)
-        mp_bucket = min(self.max_pages_per_seq, _pow2(max_pages))
-        self._note_occupancy()
-        if self._spec:
-            self._spec_round(active, mp_bucket)
-        else:
-            self._decode_one(active, mp_bucket)
-        self.steps += 1
+            self._retire_finished()
+            return bool(self.slots or self.pending)
 
-        for slot in list(self.slots):
-            rec = self.slots[slot]
-            if rec.prefilling:
-                continue
-            if len(rec.generated) >= rec.req.max_new_tokens:
-                self._retire(slot, rec)
-        return bool(self.slots or self.pending)
+    def _count_decode_step(self) -> None:
+        """Counters of one decode launch, from host-side state: the pool's
+        pages and the KV tokens held (each decoding slot's with the token
+        this launch writes)."""
+        if not obs.enabled():
+            return
+        obs.incr("engine.decode_steps")
+        obs.incr("engine.kv.pages_held",
+                 self.n_pages - 1 - self.alloc.free_pages)
+        obs.incr("engine.kv.tokens_held", sum(
+            r.prefill_cursor if r.prefilling
+            else len(r.req.prompt) + len(r.generated)
+            for r in self.slots.values()))
 
     def report(self) -> dict:
         """Engine-level metrics (the run report, DESIGN.md §13): counts are
@@ -981,7 +1037,6 @@ class PagedEngine:
     def run(self) -> dict:
         """Drive :meth:`step` until idle; returns {uid: tokens} results.
         :meth:`report` carries the run's engine metrics."""
-        with obs.span("engine.run"):
-            while self.step():
-                pass
+        while self.step():
+            pass
         return self.results

@@ -82,9 +82,8 @@ class ShardedPagedEngine:
         return merged
 
     def run(self) -> dict:
-        with obs.span("sharded_engine.run"):
-            while self.step():
-                pass
+        while self.step():
+            pass
         return self.results
 
     # -- reporting --------------------------------------------------------

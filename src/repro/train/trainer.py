@@ -212,15 +212,18 @@ def train_loop(model, data_iter, num_steps: int, opt_cfg: AdamWConfig, *,
     step = int(jax.device_get(state["step"]))
     while step < num_steps:
         try:
-            batch = next(data_iter)
-            pin_bucket_policies(model, batch, pinned_policies, log=log,
-                                mesh=mesh)
-            t0 = time.perf_counter()
-            if failure_injector is not None:
-                failure_injector.maybe_fail(step)
             with obs.span("trainer.step", step=step):
-                state, metrics = step_fn(state, batch)
-                loss = float(jax.device_get(metrics["loss"]))
+                with obs.span("trainer.data"):
+                    batch = next(data_iter)
+                    pin_bucket_policies(model, batch, pinned_policies,
+                                        log=log, mesh=mesh)
+                t0 = time.perf_counter()
+                if failure_injector is not None:
+                    failure_injector.maybe_fail(step)
+                with obs.span("trainer.dispatch"):
+                    state, metrics = step_fn(state, batch)
+                with obs.span("trainer.loss_read"):
+                    loss = float(jax.device_get(metrics["loss"]))
             dt = time.perf_counter() - t0
             obs.incr("trainer.steps")
             if watchdog is not None:
