@@ -7,8 +7,8 @@ paper's wins are largest (1.2-2.4×, memory-bound + GQA). Per DESIGN.md §7:
 policy the autotuner picks, its achieved-bandwidth fraction, and the
 modeled speedup over a no-split launch (one grid cell per (batch, kv_head),
 which under-occupies the DMA pipeline exactly when batch × kv_heads is
-small — the split-KV story). A paged-layout row shows the page-granular
-split's overhead vs the tuned contiguous split.
+small — the split-KV story). A paged-layout row shows the split of whole
+pages that the paged policy derives, against the tuned contiguous split.
 """
 from __future__ import annotations
 
@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.core import autotune
 from repro.core import perf_model as pm
-from repro.kernels.attention import attention_decode
+from repro.kernels.attention import attention_decode, resolve_decode_policy
 from .common import measure_cell, emit
 
 
@@ -45,7 +45,8 @@ def _row(name, b, h, hkv, skv, d, *, page_size=None):
                                      (b, hkv, group, skv, d))
         block_kv = pol.block_kv
     else:
-        block_kv = page_size
+        block_kv = resolve_decode_policy(b, hkv, group, skv, d, q.dtype,
+                                         page_size=page_size).block_kv
     tuned = _modeled(b, hkv, group, skv, d, block_kv)
     nosplit = _modeled(b, hkv, group, skv, d, skv)
     emit(name, us,
@@ -66,7 +67,7 @@ def main() -> None:
             for group in groups:
                 hkv = h // group
                 _row(f"decode_s{skv}_b{b}_g{group}", b, h, hkv, skv, d)
-    # paged layout: split size pinned to the physical page
+    # paged layout: the split is a block of whole pages
     skv, b, group = seqs[-1], batches[0], groups[-1]
     page = 64 if smoke else 256
     _row(f"decode_paged_s{skv}_b{b}_g{group}_p{page}", b, h, h // group,

@@ -19,14 +19,19 @@ Two cache layouts share the kernel body:
   absolute position (slot = pos % S), which drives the validity and
   sliding-window masks.
 * :func:`flash_decode_paged` — a (P, Hkv, page, D) page pool indexed
-  through a scalar-prefetched per-sequence page table: grid dim 2 walks the
-  table and the K/V BlockSpec index_map dereferences it, so each step DMAs
-  one physical page (block_kv == page_size by construction). Never-written
-  table entries point at the reserved null page 0; the length mask zeroes
-  their contribution in the combine.
+  through a scalar-prefetched per-sequence page table. The pool stays in
+  HBM; grid (B, head groups, blocks) walks the table a block of
+  ``block_kv // page_size`` whole pages at a time, each page one DMA of
+  all the step's heads into a double-buffered VMEM block (all KV heads
+  for single-token decode, one head for a tall multi-token tile). Blocks
+  at or past a slot's length copy and compute nothing, so a launch costs
+  the pages the slots hold, not the bucket's; the next block with work is
+  copied while the current one is reduced. Never-written table entries
+  point at the reserved null page 0 and are neither copied nor counted.
 
 Policies come from ``repro.core.policy`` (op kind ``attention_decode``,
-bandwidth-dominated perf model); block_n is the split size.
+bandwidth-dominated perf model); block_n is the split size (for the paged
+pool, ``resolve_decode_policy`` derives it from the launch shape).
 
 Epilogue chains (DESIGN.md §12) split across the two halves: the gemma2
 ``softcap`` is per-logit, so it runs inside the split kernels (on the
@@ -130,53 +135,146 @@ def _decode_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
     l_ref[0, 0, 0] = l
 
 
-def _decode_kernel_paged(page_table_ref, lengths_ref, q_ref, k_ref, v_ref,
-                         o_ref, m_ref, l_ref, *, page_size: int, scale: float,
-                         window: int | None, softcap: float = 0.0,
-                         q_tokens: int = 1):
-    """Paged variant: grid (B, Hkv, max_pages); one physical page per step.
+def _decode_kernel_paged(page_table_ref, lengths_ref, q_ref, k_hbm, v_hbm,
+                         o_ref, m_ref, l_ref, k_buf, v_buf, k_sem, v_sem,
+                         state, *, pages_per_block: int, page_size: int,
+                         scale: float, window: int | None,
+                         softcap: float = 0.0, q_tokens: int = 1):
+    """Paged variant: grid (B, head groups, blocks); a step walks one block
+    of ``pages_per_block`` pages for every head of its group.
 
-    ``q_tokens`` > 1 is the speculative-verify shape: the q tile packs
-    T = q_tokens query positions per GQA group row-major (row = g*T + t),
-    token t sitting at absolute position ``length - T + t``, so each row
-    gets its own causal (and window) mask.
+    K/V stay in HBM. Each page of a block is one DMA of
+    ``(heads, page, D)`` into a two-slot VMEM buffer; a step starts the
+    copies of the next grid step that has work before it computes, so
+    the pool streams while the previous block is reduced. A block whose
+    first token lies at or past the slot's length copies and computes
+    nothing and writes the empty partial (0, MASK_VALUE, 0). Inside a
+    block only the pages the slot has written are copied; the buffer is
+    zeroed once, so a page slot never copied holds finite values, which
+    the per-token mask zeroes.
+
+    ``state`` (SMEM): [0] the buffer slot of the next block with work,
+    [1] whether its copies have been started.
+
+    ``q_tokens`` > 1 is the speculative-verify / chunk shape: the q tile
+    packs T = q_tokens query positions per GQA group row-major
+    (row = g*T + t), token t sitting at absolute position
+    ``length - T + t``, so each row gets its own causal (and window) mask.
     """
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    length = lengths_ref[b]
-    if q_tokens == 1:
-        idx = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size,), 0)
-        valid = idx < length
-        if window is not None:
-            valid &= (length - 1 - idx) < window
-    else:
+    b, hg, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    n_b, n_hg, n_blocks = (pl.num_programs(0), pl.num_programs(1),
+                           pl.num_programs(2))
+    heads, mp = k_buf.shape[1], page_table_ref.shape[1]
+    bt = pages_per_block * page_size
+
+    def has_work(bb, jj):
+        return jj * bt < lengths_ref[bb]
+
+    def copy_pages(bb, hh, jj, slot, start):
+        """Start (or wait for) the K and V copies of block jj of slot bb's
+        head group hh into buffer ``slot``: one copy per page holding
+        written tokens."""
+        n_live = jnp.minimum(pl.cdiv(lengths_ref[bb] - jj * bt, page_size),
+                             pages_per_block)
+        src = pl.ds(hh * heads, heads)
+
+        def page(i, carry):
+            # a table shorter than the block: its last entry, never live
+            pid = page_table_ref[bb, jnp.minimum(jj * pages_per_block + i,
+                                                 mp - 1)]
+            dst = pl.ds(i * page_size, page_size)
+            for hbm, buf, sem in ((k_hbm, k_buf, k_sem),
+                                  (v_hbm, v_buf, v_sem)):
+                copy = pltpu.make_async_copy(hbm.at[pid, src],
+                                             buf.at[slot, :, dst],
+                                             sem.at[slot])
+                if start:
+                    copy.start()
+                else:
+                    copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, n_live, page, 0)
+
+    def next_step(bb, hh, jj):
+        """The first grid step after (bb, hh, jj) that has work; its slot
+        index is n_b when none has."""
+        more_blocks = (jj + 1 < n_blocks) & has_work(bb, jj + 1)
+        same_slot = more_blocks | (hh + 1 < n_hg)
+        later = jax.lax.fori_loop(
+            jnp.where(same_slot, n_b, bb + 1), n_b,
+            lambda i, f: jnp.where((f == n_b) & (lengths_ref[i] > 0), i, f),
+            n_b)
+        return (jnp.where(same_slot, bb, later),
+                jnp.where(more_blocks, hh, jnp.where(same_slot, hh + 1, 0)),
+                jnp.where(more_blocks, jj + 1, 0))
+
+    @pl.when((b == 0) & (hg == 0) & (j == 0))
+    def _init():
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        state[0] = 0
+        state[1] = 0
+
+    @pl.when(has_work(b, j))
+    def _compute():
+        slot = state[0]
+
+        @pl.when(state[1] == 0)
+        def _():                          # the first block with work
+            copy_pages(b, hg, j, slot, start=True)
+
+        nb, nh, nj = next_step(b, hg, j)
+
+        @pl.when(nb < n_b)
+        def _():
+            copy_pages(nb, nh, nj, 1 - slot, start=True)
+
+        state[0] = 1 - slot
+        state[1] = (nb < n_b).astype(jnp.int32)
+        copy_pages(b, hg, j, slot, start=False)
+
+        length = lengths_ref[b]
         rows = q_ref.shape[2]
-        pos_row = length - q_tokens + (
-            jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0)
-            % q_tokens)
-        idx = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 1)
-        valid = idx <= pos_row
-        if window is not None:
-            valid &= (pos_row - idx) < window
-    o, m, l = _split_partials(q_ref[0, 0].astype(jnp.float32),
-                              k_ref[0, 0], v_ref[0, 0], valid, scale, softcap)
-    o_ref[0, 0, 0] = o
-    m_ref[0, 0, 0] = m
-    l_ref[0, 0, 0] = l
+        if q_tokens == 1:
+            idx = j * bt + jax.lax.broadcasted_iota(jnp.int32, (bt,), 0)
+            valid = idx < length
+            if window is not None:
+                valid &= (length - 1 - idx) < window
+        else:
+            pos_row = length - q_tokens + (
+                jax.lax.broadcasted_iota(jnp.int32, (rows, bt), 0)
+                % q_tokens)
+            idx = j * bt + jax.lax.broadcasted_iota(jnp.int32, (rows, bt), 1)
+            valid = idx <= pos_row
+            if window is not None:
+                valid &= (pos_row - idx) < window
+        for h in range(heads):
+            o, m, l = _split_partials(q_ref[0, h].astype(jnp.float32),
+                                      k_buf[slot, h], v_buf[slot, h], valid,
+                                      scale, softcap)
+            o_ref[0, h, 0] = o
+            m_ref[0, h, 0] = m
+            l_ref[0, h, 0] = l
+
+    @pl.when(jnp.logical_not(has_work(b, j)))
+    def _empty():
+        o_ref[...] = jnp.zeros_like(o_ref)
+        m_ref[...] = jnp.full_like(m_ref, MASK_VALUE)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
 
-def _partial_specs(b, hkv, n_splits, g, d):
-    """(out_specs, out_shapes) of the per-split partials + stats. The
-    stats are (g, 1) columns: every block's trailing two dims equal the
-    array's, which the TPU lowering requires for blocks this small."""
+def _partial_specs(b, hkv, n_splits, g, d, heads=1):
+    """(out_specs, out_shapes) of the per-split partials + stats, ``heads``
+    KV heads a grid step. The stats are (g, 1) columns: every block's
+    trailing two dims equal the array's, which the TPU lowering requires
+    for blocks this small."""
     part_map = lambda b_, h_, j_, *_: (b_, h_, j_, 0, 0)
     out_specs = [
-        tiles.block_spec((1, 1, 1, g, d), part_map, jnp.float32,
+        tiles.block_spec((1, heads, 1, g, d), part_map, jnp.float32,
                          allow_ragged_minor=True),   # q rows = GQA group
-        pl.BlockSpec((1, 1, 1, g, 1), part_map),
-        pl.BlockSpec((1, 1, 1, g, 1), part_map),
+        pl.BlockSpec((1, heads, 1, g, 1), part_map),
+        pl.BlockSpec((1, heads, 1, g, 1), part_map),
     ]
     out_shapes = [
         jax.ShapeDtypeStruct((b, hkv, n_splits, g, d), jnp.float32),
@@ -245,6 +343,29 @@ def flash_decode(q, k, v, lengths, *, policy: KernelPolicy,
                           sinks=sinks).astype(q.dtype)
 
 
+def paged_heads_per_step(kv_heads: int, q_tokens: int) -> int:
+    """KV heads one paged grid step computes: all of them for single-token
+    decode (a page's heads are one contiguous DMA), one for the tall
+    multi-token tile of a chunk or verify step."""
+    return kv_heads if q_tokens == 1 else 1
+
+
+def paged_vmem_bytes(*, kv_heads: int, group: int, q_tokens: int,
+                     pages_per_block: int, page_size: int, head_dim: int,
+                     dtype) -> int:
+    """VMEM working set of one :func:`flash_decode_paged` step: the K/V
+    block buffers (two slots each), the pipelined q, partial and stat
+    blocks (two buffers each), and one head's f32 logits, probabilities
+    and mask over the block."""
+    heads = paged_heads_per_step(kv_heads, q_tokens)
+    rows, bt = group * q_tokens, pages_per_block * page_size
+    kv = 2 * 2 * tiles.padded_tile_bytes((heads, bt, head_dim), dtype)
+    io = 2 * (tiles.padded_tile_bytes((heads, rows, head_dim), dtype)
+              + tiles.padded_tile_bytes((heads, rows, head_dim), jnp.float32)
+              + 2 * tiles.padded_tile_bytes((heads, rows, 1), jnp.float32))
+    return kv + io + 3 * tiles.padded_tile_bytes((rows, bt), jnp.float32)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("policy", "window", "logit_scale", "softcap",
@@ -255,7 +376,8 @@ def flash_decode_paged(q, k_pages, v_pages, page_table, lengths, *,
                        logit_scale: float | None = None,
                        softcap: float = 0.0, sinks=None,
                        interpret: bool = True, q_tokens: int = 1):
-    """Split-KV decode over a paged KV pool (one split == one page).
+    """Split-KV decode over a paged KV pool (one split == one block of
+    ``policy.block_kv // page_size`` pages).
 
     q: (B, Hkv, G, D); k_pages/v_pages: (P, Hkv, page_size, D) physical
     pools; page_table: (B, MP) int32 physical page ids (0 = reserved null
@@ -265,45 +387,54 @@ def flash_decode_paged(q, k_pages, v_pages, page_table, lengths, *,
 
     ``q_tokens`` > 1: G packs group * q_tokens rows (row = g*T + t) and
     row t attends through position ``lengths - q_tokens + t`` — the
-    speculative-decoding verify step, which streams the KV pool exactly
-    once for all T tokens.
+    speculative-decoding verify step and the prefill chunk, which stream
+    the KV pool exactly once for all T tokens.
     """
     b, hkv, g, d = q.shape
     n_pages, _, page_size, _ = k_pages.shape
     mp = page_table.shape[1]
-    assert policy.block_kv == page_size, (policy.block_kv, page_size)
+    assert policy.block_kv % page_size == 0, (policy.block_kv, page_size)
+    ppb = policy.block_kv // page_size
+    n_blocks = pl.cdiv(mp, ppb)
+    heads = paged_heads_per_step(hkv, q_tokens)
     scale = logit_scale if logit_scale is not None else d ** -0.5
     policy.check()
+    used = paged_vmem_bytes(kv_heads=hkv, group=g // q_tokens,
+                            q_tokens=q_tokens, pages_per_block=ppb,
+                            page_size=page_size, head_dim=d,
+                            dtype=k_pages.dtype)
+    assert used <= tiles.VMEM_BYTES, (used, ppb)
     page_table = jnp.asarray(page_table, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32).reshape(b)
 
-    ragged_kv = tiles.shape_ragged(page_size, d, k_pages.dtype)
     q_map = lambda b_, h_, j_, *_: (b_, h_, 0, 0)
-    # the paged-attention indirection: the K/V block for grid step (b, h, j)
-    # is whatever physical page the (scalar-prefetched) table names
-    kv_map = lambda b_, h_, j_, pt_ref, len_ref: (pt_ref[b_, j_], h_, 0, 0)
-    out_specs, out_shapes = _partial_specs(b, hkv, mp, g, d)
-
-    kernel = functools.partial(_decode_kernel_paged, page_size=page_size,
-                               scale=scale, window=window, softcap=softcap,
+    out_specs, out_shapes = _partial_specs(b, hkv, n_blocks, g, d, heads)
+    kernel = functools.partial(_decode_kernel_paged, pages_per_block=ppb,
+                               page_size=page_size, scale=scale,
+                               window=window, softcap=softcap,
                                q_tokens=q_tokens)
+    buf = pltpu.VMEM((2, heads, ppb * page_size, d), k_pages.dtype)
     o, m, l = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, hkv, mp),
+            grid=(b, hkv // heads, n_blocks),
             in_specs=[
-                tiles.block_spec((1, 1, g, d), q_map, q.dtype,
+                tiles.block_spec((1, heads, g, d), q_map, q.dtype,
                                  allow_ragged_minor=True),
-                tiles.block_spec((1, 1, page_size, d), kv_map, k_pages.dtype,
-                                 allow_ragged_minor=ragged_kv),
-                tiles.block_spec((1, 1, page_size, d), kv_map, v_pages.dtype,
-                                 allow_ragged_minor=ragged_kv),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=out_specs,
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((2,), jnp.int32)],
         ),
         out_shape=out_shapes,
-        compiler_params=tiles.compiler_params(),
+        # a step starts the copies of the next step with work: the grid
+        # runs in order
+        compiler_params=tiles.compiler_params(
+            dimension_semantics=("arbitrary",) * 3),
         interpret=interpret,
     )(page_table, lengths, q, k_pages, v_pages)
     if sinks is not None:

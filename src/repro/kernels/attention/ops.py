@@ -28,19 +28,24 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
-from repro.core import autotune
+from repro.core import autotune, tiles
 from repro.core.policy import (KernelPolicy, legacy_attention_blocks,
                                make_policy, resolve_policy)
 from repro.kernels.modes import interpret_for
 from .epilogue import AttnEpilogue
 from .kernel_fwd import flash_attention_fwd
 from .kernel_bwd import flash_attention_bwd
-from .kernel_decode import flash_decode, flash_decode_paged
+from .kernel_decode import (flash_decode, flash_decode_paged,
+                            paged_heads_per_step, paged_vmem_bytes)
 from .ref import attention_ref, attention_ref_chunked, decode_ref
 
 # above this KV length, 'reference' mode switches to the chunked
 # online-softmax scan so temps stay O(S·chunk) instead of O(S^2)
 _CHUNKED_THRESHOLD = 2048
+# tokens of pages one paged-decode grid step walks (a whole number of
+# pages): enough that the fixed cost of a grid step is small beside the
+# block's DMA, few enough that a slot's last block wastes little
+_PAGED_BLOCK_TOKENS = 512
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
@@ -187,23 +192,33 @@ def resolve_decode_policy(batch: int, kv_heads: int, group: int, kv_len: int,
     """The decode policy for a launch signature (DESIGN.md §5 / §8).
 
     Contiguous caches go through the autotuner (the split size is the one
-    free axis of the bandwidth-dominated model). Paged caches have their
-    split size fixed by the physical page (one page per grid step by
-    construction), so the policy is built directly — deterministically, so
-    an engine's pinned policy and the traced policy are the same object
-    semantics as the autotuner's memoized path. A non-identity ``epilogue``
-    rides the policy for reporting (decode's sink stage lives in the jnp
-    LSE combine, so it never affects decode VMEM legality).
+    free axis of the bandwidth-dominated model). Paged caches split by
+    blocks of whole pages: about ``_PAGED_BLOCK_TOKENS`` tokens of pages a
+    grid step, capped at the table's ``kv_len // page_size`` pages and
+    halved while the kernel's working set (``paged_vmem_bytes``: a tall
+    ``q_tokens`` tile) overflows VMEM. The policy is built directly from
+    the shape — deterministically, so an engine's pinned policy and the
+    traced policy are the same object semantics as the autotuner's
+    memoized path. A non-identity ``epilogue`` rides the policy for
+    reporting (decode's sink stage lives in the jnp LSE combine, so it
+    never affects decode VMEM legality).
     """
     ep = epilogue if epilogue is not None and not epilogue.is_identity else None
     if page_size is None:
         return autotune.select_policy(
             "attention_decode", (batch, kv_heads, group, kv_len, head_dim),
             str(dtype), epilogue=ep)
+    ppb = max(1, min(_PAGED_BLOCK_TOKENS // page_size, kv_len // page_size))
+    while ppb > 1 and paged_vmem_bytes(
+            kv_heads=kv_heads, group=group, q_tokens=q_tokens,
+            pages_per_block=ppb, page_size=page_size, head_dim=head_dim,
+            dtype=dtype) > tiles.VMEM_BYTES:
+        ppb //= 2
     # q tile rows = GQA group × verify tokens (q_tokens > 1 is the
-    # speculative verify step — same paged split, taller q tile)
+    # speculative verify step or a prefill chunk — same paged split,
+    # taller q tile)
     pol = make_policy("attention_decode", block_m=group * q_tokens,
-                      block_n=page_size, block_k=head_dim,
+                      block_n=ppb * page_size, block_k=head_dim,
                       in_dtype=str(jnp.dtype(dtype)),
                       name="paged" if q_tokens == 1 else f"paged_q{q_tokens}",
                       epilogue=ep)
@@ -314,8 +329,10 @@ def attention_decode_paged(q, k_pages, v_pages, page_table, lengths, *,
             sig = autotune.OpSignature("attention_decode",
                                        (b, hkv, group * t, mp * page_size, d),
                                        str(q.dtype), epilogue=policy.epilogue)
+            ppb = policy.block_kv // page_size
             obs.launch("attention_decode", variant="paged",
-                       grid=(b, hkv, mp), policy=policy,
+                       grid=(b, hkv // paged_heads_per_step(hkv, t),
+                             -(-mp // ppb)), policy=policy,
                        dma_bytes=autotune.score_policy(sig, policy).dma_bytes,
                        flops=4 * b * h * t * mp * page_size * d)
         out = flash_decode_paged(qg, k_pages, v_pages, page_table, lengths,

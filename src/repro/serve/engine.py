@@ -417,6 +417,9 @@ class PagedEngine:
         self.preemptions = 0
         self.admissions = 0
         self.tokens_generated = 0
+        # paged-decode blocks the kernel walks / the launch grid holds
+        self.kv_blocks_walked = 0
+        self.kv_blocks_in_grid = 0
         self.chunks_prefilled = 0
         self.spec_rounds = 0
         self.spec_proposed = 0
@@ -824,6 +827,8 @@ class PagedEngine:
     def _decode_one(self, active: list, mp_bucket: int) -> None:
         """One single-token decode step for every decode-ready slot."""
         entry = self._decode_bucket(mp_bucket)
+        self._count_decode_step(active, mp_bucket,
+                                entry["policies"]["attention_decode"])
         n_active = len(active)
         with obs.span("engine.decode_launch", active_slots=n_active,
                       mp_bucket=mp_bucket):
@@ -870,6 +875,8 @@ class PagedEngine:
         k = self.spec_tokens
         dentry = self._decode_bucket(mp_bucket, draft=True)
         ventry = self._verify_bucket(mp_bucket)
+        self._count_decode_step(active, mp_bucket,
+                                ventry["policies"]["attention_decode"])
         pt, lens, act = self._launch_views(active, mp_bucket)
         base = np.asarray(self.state["lengths"])
 
@@ -970,7 +977,6 @@ class PagedEngine:
             max_pages = max(self.slots[s].n_pages for s in active)
             mp_bucket = min(self.max_pages_per_seq, _pow2(max_pages))
             self._note_occupancy()
-            self._count_decode_step()
             if self._spec:
                 self._spec_round(active, mp_bucket)
             else:
@@ -979,12 +985,22 @@ class PagedEngine:
             self._retire_finished()
             return bool(self.slots or self.pending)
 
-    def _count_decode_step(self) -> None:
-        """Counters of one decode launch, from host-side state: the pool's
-        pages and the KV tokens held (each decoding slot's with the token
-        this launch writes)."""
+    def _count_decode_step(self, active: list, mp_bucket: int,
+                           policy) -> None:
+        """Counters of one decode launch, from host-side state: the paged
+        kernel's blocks of ``policy.block_kv`` tokens that the decoding
+        slots fill (the blocks it walks; the rest it skips) and that its
+        grid holds, the pool's pages and the KV tokens held (each decoding
+        slot's with the token this launch writes)."""
+        ppb = policy.block_kv // self.page_size
+        walked = sum(-(-self.slots[s].n_pages // ppb) for s in active)
+        in_grid = self.batch_slots * -(-mp_bucket // ppb)
+        self.kv_blocks_walked += walked
+        self.kv_blocks_in_grid += in_grid
         if not obs.enabled():
             return
+        obs.incr("engine.kv.blocks_walked", walked)
+        obs.incr("engine.kv.blocks_in_grid", in_grid)
         obs.incr("engine.decode_steps")
         obs.incr("engine.kv.pages_held",
                  self.n_pages - 1 - self.alloc.free_pages)
@@ -1004,6 +1020,8 @@ class PagedEngine:
             "tokens_generated": self.tokens_generated,
             "peak_pages_in_use": self.peak_pages_in_use,
             "page_pool_size": self.n_pages - 1,
+            "kv_blocks": {"walked": self.kv_blocks_walked,
+                          "in_grid": self.kv_blocks_in_grid},
             "bucket_lru": dict(self.lru_stats),
             "completed": len(self.results),
         }

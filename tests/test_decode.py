@@ -123,9 +123,23 @@ class TestDecodePolicy:
         assert 8192 // pol.block_kv > 1
 
     def test_paged_policy_fixes_split_to_page(self):
-        pol = resolve_decode_policy(2, 4, 2, 256, 64, "bfloat16",
-                                    page_size=32)
-        assert pol.block_kv == 32
+        """A paged split is a block of whole pages, about 512 tokens,
+        within the table, and a function of the launch shape alone (the
+        engine's pinned policy is the traced one); a tall multi-token q
+        tile that would overflow VMEM halves it."""
+        for page, kv_len, want in ((32, 256, 256), (64, 4096, 512),
+                                   (16, 64, 64), (1024, 4096, 1024)):
+            pol = resolve_decode_policy(2, 4, 2, kv_len, 64, "bfloat16",
+                                        page_size=page)
+            assert pol.block_kv == want
+            assert pol.block_kv % page == 0 and pol.block_kv <= kv_len
+            assert pol == resolve_decode_policy(2, 4, 2, kv_len, 64,
+                                                "bfloat16", page_size=page)
+        chunk = resolve_decode_policy(1, 8, 4, 4096, 128, "bfloat16",
+                                      page_size=64, q_tokens=512)
+        tall = resolve_decode_policy(1, 8, 4, 4096, 128, "bfloat16",
+                                     page_size=64, q_tokens=4096)
+        assert (chunk.block_kv, tall.block_kv) == (512, 256)
 
     def test_policies_for_model_includes_decode(self):
         cfg = get_config("granite-8b", smoke=True)
@@ -176,21 +190,61 @@ class TestPagedCache:
         want = np.concatenate([np.asarray(k)] + extra, axis=2)
         np.testing.assert_array_equal(got[:, :, : s_true + 6], want)
 
-    def test_paged_kernel_matches_reference(self):
+    # (page, mp, heads, kv heads, lengths, extras): the default policy walks
+    # 512 tokens of pages a grid step, so page 128 gives 4-page blocks and
+    # page 256 2-page blocks. Lengths sit at 0, 1, page - 1, a block edge
+    # +-1 and a full table; a slot's table entries past its pages are the
+    # null page 0.
+    _EDGES = [0, 1, 127, 511, 512, 513, 768]
+    _PAGED_CASES = {
+        "small-window-none": (16, 4, 4, 2, [55, 20], {}),
+        "small-window-8": (16, 4, 4, 2, [55, 20], {"window": 8}),
+        "edges-gqa4": (128, 6, 8, 2, _EDGES, {}),
+        "edges-mha": (128, 6, 2, 2, _EDGES, {}),
+        "edges-bf16": (128, 6, 8, 2, _EDGES, {"dtype": jnp.bfloat16}),
+        "window-softcap": (128, 6, 4, 2, _EDGES,
+                           {"window": 200, "softcap": 5.0}),
+        "sinks": (256, 5, 8, 2, [0, 255, 256, 511, 513, 1280],
+                  {"sinks": True}),
+        "table-shorter-than-block": (128, 3, 4, 2, [0, 130, 384],
+                                     {"block_pages": 4}),
+        "verify-3-tokens": (128, 6, 4, 2, [3, 128, 512, 514, 768],
+                            {"q_tokens": 3, "window": 300}),
+    }
+
+    @pytest.mark.parametrize("case", list(_PAGED_CASES))
+    def test_paged_kernel_matches_reference(self, case):
+        """The block-walking paged kernel against the gathered einsum
+        oracle across block edges, short tables and every epilogue."""
+        page, mp, h, hkv, lengths, ex = self._PAGED_CASES[case]
+        dtype = ex.get("dtype", jnp.float32)
+        t, b, d = ex.get("q_tokens", 1), len(lengths), 32
         rng = np.random.default_rng(2)
-        P, hkv, page, d, h, b, mp = 9, 2, 16, 32, 4, 2, 4
-        kp = jnp.asarray(rng.normal(size=(P, hkv, page, d)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(P, hkv, page, d)), jnp.float32)
-        q = jnp.asarray(rng.normal(size=(b, h, 1, d)), jnp.float32)
-        pt = jnp.array([[3, 1, 7, 0], [2, 5, 0, 0]], jnp.int32)
-        lens = jnp.array([55, 20], jnp.int32)
-        for window in (None, 8):
-            ref = attention_decode_paged(q, kp, vp, pt, lens, window=window,
-                                         mode="reference")
-            ker = attention_decode_paged(q, kp, vp, pt, lens, window=window,
-                                         mode="pallas_interpret")
-            np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
-                                       atol=5e-6)
+        held = [-(-n // page) for n in lengths]
+        P = 1 + sum(held)
+        kp = jnp.asarray(rng.normal(size=(P, hkv, page, d)), dtype)
+        vp = jnp.asarray(rng.normal(size=(P, hkv, page, d)), dtype)
+        q = jnp.asarray(rng.normal(size=(b, h, t, d)), dtype)
+        ids = rng.permutation(np.arange(1, P))
+        pt = np.zeros((b, mp), np.int32)
+        for i, n in enumerate(held):
+            pt[i, :n], ids = ids[:n], ids[n:]
+        kw = {"window": ex.get("window"), "softcap": ex.get("softcap")}
+        if ex.get("sinks"):
+            kw["sinks"] = jnp.asarray(rng.normal(size=(h,)), jnp.float32)
+        policy = None
+        if "block_pages" in ex:     # a block longer than the whole table
+            policy = resolve_decode_policy(
+                b, hkv, h // hkv, ex["block_pages"] * page, d, dtype,
+                page_size=page)
+            assert policy.block_kv == ex["block_pages"] * page > mp * page
+        args = (q, kp, vp, jnp.asarray(pt), jnp.asarray(lengths, jnp.int32))
+        ref = attention_decode_paged(*args, mode="reference", **kw)
+        ker = attention_decode_paged(*args, mode="pallas_interpret",
+                                     policy=policy, **kw)
+        atol = 5e-6 if dtype == jnp.float32 else _TOL[dtype]
+        np.testing.assert_allclose(np.asarray(ker, np.float32),
+                                   np.asarray(ref, np.float32), atol=atol)
 
     def test_allocator_lifecycle(self):
         alloc = kvc.PageAllocator(5)       # pages 1..4 usable
@@ -312,7 +366,8 @@ class TestPagedEngine:
         assert decode_keys, eng.bucket_policies
         for k in decode_keys:
             pol = eng.bucket_policies[k]["attention_decode"]
-            assert pol.block_kv == 8     # split size == page size
+            # a split is a block of whole pages within the bucket
+            assert pol.block_kv % 8 == 0 and pol.block_kv <= 8 * k[1]
 
     @pytest.mark.parametrize("arch", ["mamba2-130m", "recurrentgemma-2b"])
     def test_recurrent_arch_parity(self, arch):
@@ -360,6 +415,28 @@ class TestPagedEngine:
         for r in reqs:
             want = fixed.generate(r.prompt[None, :], r.max_new_tokens)
             np.testing.assert_array_equal(results[r.uid], want.tokens[0])
+
+    def test_kv_block_counters(self):
+        """The paged kernel's blocks walked and held in its grid, counted
+        from host state: 256-token pages make 2-page blocks, so a 3-page
+        slot walks 2 blocks and a 1-page slot 1, of the 2 x 2 blocks the
+        4-page bucket's grid holds."""
+        from repro import obs
+        model, params, cfg = self._model()
+        eng = PagedEngine(model, params, batch_slots=2, page_size=256,
+                          max_pages_per_seq=4)
+        rng = np.random.default_rng(4)
+        with obs.capture() as cap:
+            for uid, plen in enumerate((600, 5)):
+                eng.submit(Request(uid, rng.integers(
+                    0, cfg.vocab_size, plen).astype(np.int32), 4))
+            eng.run()
+        steps = cap.counter("engine.decode_steps")
+        assert steps == 3       # the first token comes from the prefill
+        assert cap.counter("engine.kv.blocks_walked") == 3 * steps
+        assert cap.counter("engine.kv.blocks_in_grid") == 4 * steps
+        assert eng.report()["kv_blocks"] == {"walked": 3 * steps,
+                                             "in_grid": 4 * steps}
 
     def test_rejects_oversized_request(self):
         model, params, cfg = self._model()

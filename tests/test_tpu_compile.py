@@ -14,7 +14,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.attention import (attention, attention_decode,
-                                     attention_decode_paged)
+                                     attention_decode_paged,
+                                     resolve_decode_policy)
 from repro.kernels.fused_norm import dropout_residual_layernorm
 from repro.kernels.gemm import Epilogue, gemm_fused, norm_prologue
 from repro.kernels.rope import rope, rope_tables
@@ -156,6 +157,27 @@ def test_split_kv_decode(one_chip, paged):
         fn = lambda q, k, v, n: attention_decode(q, k, v, n, mode=M)
         _compile(fn, one_chip, _bf(16, 32, 1, 128), _bf(16, 8, 4096, 128),
                  _bf(16, 8, 4096, 128), _i32(16))
+
+
+@pytest.mark.parametrize("batch,q_tokens", [(32, 1), (1, 512)])
+def test_paged_decode_at_chat_shapes(one_chip, batch, q_tokens):
+    """The paged kernel at the serving cell's shapes: the decode step (32
+    slots) and a 512-token prefill chunk over a 769 x 8 x 64 x 128 pool
+    and 64-page tables. The call keeps its name, and its partials hold
+    one split per block of pages, not one per page."""
+    fn = lambda q, kp, vp, pt, n: attention_decode_paged(
+        q, kp, vp, pt, n, mode=M)
+    text = _compile(fn, one_chip, _bf(batch, 32, q_tokens, 128),
+                    _bf(769, 8, 64, 128), _bf(769, 8, 64, 128),
+                    _i32(batch, 64), _i32(batch))
+    pol = resolve_decode_policy(batch, 8, 4, 64 * 64, 128, jnp.bfloat16,
+                                page_size=64, q_tokens=q_tokens)
+    splits = -(-64 // (pol.block_kv // 64))
+    assert splits < 64
+    call = [ln for ln in text.splitlines()
+            if ln.lstrip().startswith("%flash_decode_paged")]
+    assert call, "no custom call named flash_decode_paged"
+    assert f"f32[{batch},8,{splits},{4 * q_tokens},128]" in call[0]
 
 
 @pytest.mark.parametrize("plan", ["ring", "gather"])
