@@ -67,12 +67,13 @@ class StepProbe:
             def probed(state, batch):
                 self.calls += 1
                 if self.calls == 2:
-                    m = system.canonical(state["opt"]["m"])
+                    m = system.canonical(self.spec, state["opt"]["m"])
                     self.grad = {n: np.asarray(v, np.float32)
                                  / (1 - self.b1) for n, v in m.items()}
                 elif self.calls == STEPS + 1:
                     self.change = change_norms(
-                        self.spec, system.canonical(state["params"]),
+                        self.spec,
+                        system.canonical(self.spec, state["params"]),
                         self.seed)
                 return step(state, batch)
             return probed

@@ -6,29 +6,23 @@ Attention is causal: a query at position p attends p + 1 keys.
 """
 from __future__ import annotations
 
-from .model_spec import ModelSpec
-
-
-def matmul_params(spec: ModelSpec) -> int:
-    """Weights of the matmuls of one layer."""
-    q, kv = spec.heads * spec.hd, spec.kv_heads * spec.hd
-    return spec.d * (q + 2 * kv) + q * spec.d + 3 * spec.d * spec.f
+from .model_spec import ModelSpec, family
 
 
 def head_params(spec: ModelSpec) -> int:
     return spec.d * spec.vocab
 
 
-def attn_flops(spec: ModelSpec, keys: int) -> float:
+def attn_flops(spec: ModelSpec, keys) -> float:
     """Forward FLOPs of one query row over ``keys`` keys, all layers."""
-    return 4.0 * spec.heads * spec.hd * keys * spec.layers
+    return family(spec).attn_flops(spec, keys)
 
 
 def train_flops_per_token(spec: ModelSpec, seq: int) -> float:
     """Forward + backward (3x forward) FLOPs per token of a causal
     sequence of ``seq`` tokens: 6 N (layers' matmuls and the LM head) plus
     the attention scores and values at the mean causal context."""
-    n = spec.layers * matmul_params(spec) + head_params(spec)
+    n = family(spec).body_params(spec) + head_params(spec)
     return 6.0 * n + 3.0 * attn_flops(spec, (seq + 1) / 2)
 
 
@@ -36,7 +30,7 @@ def serve_step_flops(spec: ModelSpec, decode_ctx, chunks) -> float:
     """One engine step: a decode row per entry of ``decode_ctx`` (keys
     attended) through every layer and the head; each prefill chunk
     (start, tokens) through every layer, and the head for its last row."""
-    body = 2.0 * spec.layers * matmul_params(spec)
+    body = 2.0 * family(spec).body_params(spec)
     head = 2.0 * head_params(spec)
     f = sum(body + head + attn_flops(spec, c) for c in decode_ctx)
     for start, n in chunks:
@@ -59,6 +53,13 @@ def paged_attention_call(spec: ModelSpec, rows: list, kv_bytes: int = 2):
         flops += 4.0 * spec.heads * spec.hd * keys
         bytes_ += (cached + new) * kv_row + 2 * nq * q_row
     return flops, bytes_
+
+
+def paged_attention_launch(spec: ModelSpec, rows: list, peak) -> float:
+    """Least time of one launch's paged attention calls, every layer's:
+    the family says which rows each layer's call sees."""
+    return sum(n * least_time(*paged_attention_call(spec, r), peak)[0]
+               for r, n in family(spec).paged_layers(spec, rows))
 
 
 def least_time(flops: float, bytes_: float, peak) -> tuple[float, str]:

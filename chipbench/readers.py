@@ -27,12 +27,11 @@ def paged_attention_roofline(run):
     steps = w.steps[w.traced_steps:] if w.traced_steps >= 0 else []
     if not calls or not steps:
         return None
-    spec, least = run.spec, 0.0
+    least = 0.0
     for st in steps:
         launches = [[(1, c - 1, 1) for c in st.decode_ctx]] if st.decode_ctx \
             else []
         launches += [[(n, start, n)] for start, n in st.chunks]
         for rows in launches:
-            least += flops.least_time(
-                *flops.paged_attention_call(spec, rows), run.peak)[0]
-    return 100.0 * least * spec.layers / run.trace.seconds(calls)
+            least += flops.paged_attention_launch(run.spec, rows, run.peak)
+    return 100.0 * least / run.trace.seconds(calls)

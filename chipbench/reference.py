@@ -1,14 +1,12 @@
-"""The plain reference: the dense decoder the configuration files describe,
-written from the published description in float32 ``jax.numpy`` at the
-highest matmul precision, with no kernels, cache or batching. It imports
-nothing of the program. Layers run one at a time under a scan, each upcast
-from the weights as held only inside its own step, so the reference fits
-beside nothing else on the chip.
-
-    x = embed[tokens] * scale_emb
-    per layer: x += r * Wo attn(rope(q), rope(k), v) of rmsnorm(x) * g1
-               x += r * Wdown (silu(h Wgate) * (h Wup)), h = rmsnorm(x) * g2
-    logits = rmsnorm(x) * g @ head / (d / dim_model_base)
+"""The plain reference: the model the configuration files describe, written
+from the published description in float32 ``jax.numpy`` at the highest
+matmul precision, with no kernels, cache or batching. It imports nothing of
+the program. The configuration's family (``families/<family>.py``) gives
+the architecture (``hidden``, ``logits``) from the pieces here; the
+readings built on them (``served_gaps``, ``loss``) and the optimizer are
+the same for every family. A family runs its layers one at a time under a
+scan, each upcast from the weights as held only inside its own step, so
+the reference fits beside nothing else on the chip.
 
 RoPE rotates the two halves of each head (x1, x2) -> (x1 c - x2 s,
 x2 c + x1 s) with frequencies theta^(-i / (hd/2)); attention is causal
@@ -28,7 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .model_spec import ModelSpec
+from .model_spec import ModelSpec, family
 
 HI = jax.lax.Precision.HIGHEST
 Q_BLOCK = 512
@@ -112,37 +110,9 @@ def attention(q, k, v, quant=None):
     return out.reshape(s, h * hd)
 
 
-def layer(spec: ModelSpec, lw: dict, x, positions, quant=None):
-    """One decoder layer on one sequence x (S, d), float32."""
-    q_w = spec.heads * spec.hd
-    h = rmsnorm(x, lw["ln1"], spec.eps)
-    qk = mm(h, lw["wqk"], quant)
-    q = qk[:, :q_w].reshape(-1, spec.heads, spec.hd)
-    k = qk[:, q_w:].reshape(-1, spec.kv_heads, spec.hd)
-    v = mm(h, lw["wv"], quant).reshape(-1, spec.kv_heads, spec.hd)
-    q, k = rope(q, positions, spec.rope_theta), rope(k, positions,
-                                                     spec.rope_theta)
-    x = x + spec.res_mult * mm(attention(q, k, v, quant), lw["wo"], quant)
-    h = rmsnorm(x, lw["ln2"], spec.eps)
-    up = jax.nn.silu(mm(h, lw["w_gate"], quant)) * mm(h, lw["w_up"], quant)
-    return x + spec.res_mult * mm(up, lw["w_down"], quant)
-
-
-LAYER_KEYS = ("wqk", "wv", "wo", "w_gate", "w_up", "w_down", "ln1", "ln2")
-
-
 def hidden(spec: ModelSpec, w: dict, tokens, quant=None, remat=False):
     """Final-normed hidden states of one sequence: tokens (S,) -> (S, d)."""
-    x = w["embed"][tokens].astype(jnp.float32) * spec.emb_mult
-    positions = jnp.arange(tokens.shape[0])
-
-    def body(x, lw):
-        return layer(spec, lw, x, positions, quant), None
-
-    if remat:
-        body = jax.checkpoint(body)
-    x, _ = jax.lax.scan(body, x, {k: w[k] for k in LAYER_KEYS})
-    return rmsnorm(x, w["final_norm"], spec.eps)
+    return family(spec).hidden(spec, w, tokens, quant, remat)
 
 
 def head(spec: ModelSpec, w: dict):
@@ -150,7 +120,7 @@ def head(spec: ModelSpec, w: dict):
 
 
 def logits(spec: ModelSpec, w: dict, h, quant=None):
-    return mm(h, head(spec, w), quant) / spec.logit_div
+    return family(spec).logits(spec, w, h, quant)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 5))

@@ -8,9 +8,11 @@ The cell, its configuration, its traffic mix, its limits and its per-layer
 metrics are found by name from BENCHMARK.json (``spec.py``). Set-up (model,
 weights from the seed, warm-up) runs first; then the window measures for
 ``--seconds``; then the program's state is freed and what the window
-produced is compared with the plain reference. With ``--trace 1`` a device
-trace is taken over the end of the window and the per-layer metrics are
-reported in place of the end-to-end ones.
+produced is compared with the plain reference. With ``--trace 1`` the cell
+runs under the program's own spans and counters
+(``obs.capture(annotate=True)``), a device trace is taken over the end of
+the window, and the per-layer metrics are reported in place of the
+end-to-end ones; a plain run opens no capture.
 
 The last line of standard output is one JSON object: correct, attempted,
 failed, metrics, device (and breakdown with a trace), and last the numbers
@@ -21,6 +23,7 @@ repository, it prints no result and exits non-zero.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import json
 import os
@@ -33,6 +36,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 TRACE_SECONDS = {"serve_open_loop": 10.0, "train": 5.0}
 KINDS = {"serve_open_loop": "serve_cell", "train": "train_cell"}
+STEP_SPANS = {"serve_open_loop": "engine.step", "train": "trainer.step"}
 
 
 def log(msg: str) -> None:
@@ -78,10 +82,12 @@ class CompileLog:
 
 class Tracer:
     """The device trace of the end of the window, into a fixed directory
-    of the checkout, without the Python tracer."""
+    of the checkout, without the Python tracer; ``on_edge`` is called with
+    "trace_start" and "trace_stop"."""
 
     def __init__(self, directory: str):
         self.dir = directory
+        self.on_edge = lambda edge: None
         self._ann = None
 
     def start(self):
@@ -92,9 +98,11 @@ class Tracer:
         jax.profiler.start_trace(self.dir, profiler_options=opts)
         self._ann = jax.profiler.TraceAnnotation(WINDOW)
         self._ann.__enter__()
+        self.on_edge("trace_start")
 
     def stop(self):
         import jax
+        self.on_edge("trace_stop")
         self._ann.__exit__(None, None, None)
         jax.profiler.stop_trace()
 
@@ -116,16 +124,32 @@ class Context:
     t_window: float = 0.0
     t_closed: float = 0.0
     memory_peak: int = 0
+    rec: object = None          # the obs recorder of a traced run
+    counters_at: dict = dataclasses.field(default_factory=dict)
 
     def log(self, msg, *_, **__):
         log(f"[{self.workload}] {msg}")
 
+    def snapshot(self, edge: str):
+        """The program's counters as they stand at ``edge``."""
+        if self.rec is not None:
+            self.counters_at[edge] = dict(self.rec.counters)
+
+    def counters(self, start: str, stop: str) -> dict:
+        """Each counter's change from ``start`` to ``stop``."""
+        a, b = self.counters_at.get(start), self.counters_at.get(stop)
+        if a is None or b is None:
+            return {}
+        return {k: v - a.get(k, 0.0) for k, v in b.items()}
+
     def mark_setup(self):
         self.setup_s = process_age()
         self.t_window = time.perf_counter()
+        self.snapshot("open")
 
     def read_memory(self):
         self.t_closed = time.perf_counter()
+        self.snapshot("close")
         stats = self.device.memory_stats() or {}
         self.memory_peak = int(stats.get("peak_bytes_in_use", 0))
 
@@ -134,12 +158,20 @@ class Context:
 class Run:
     """What a per-layer metric reader gets: the configuration's sizes, the
     chip's peaks, the cell runner's record of the window, the reduced
-    trace and the compiles counted inside the window."""
+    trace (with the program's spans in it), the compiles counted inside
+    the window; the program's counters, each as its change over the
+    window (``counters["window"]``) and over the traced part
+    (``counters["traced"]``); the capture's recorder with every span and
+    interval; and the window's open and close on its clock."""
     spec: object
     peak: object
     window: object
     trace: object
     window_compiles: int
+    counters: dict = dataclasses.field(default_factory=dict)
+    rec: object = None
+    t_open: float = 0.0
+    t_close: float = 0.0
 
 
 def parse(argv):
@@ -174,7 +206,7 @@ def main(argv=None, *, root: str = ROOT, require_chip: bool = True,
     sys.path.insert(0, os.path.join(root, "src"))
     from chipbench import model_spec, peaks, spec as bench_spec, traffic
     cell = bench_spec.cell(root, args.workload)
-    spec = model_spec.load(cell.config_file)
+    spec = model_spec.load(cell.config_file, root)
     mix = traffic.load(cell.traffic_file)
 
     # the TPU runtime's logs go under the run's TMPDIR, not a fixed /tmp
@@ -195,11 +227,16 @@ def main(argv=None, *, root: str = ROOT, require_chip: bool = True,
     tracer = Tracer(trace_dir) if args.trace else None
     trace_from = (max(0.0, args.seconds - TRACE_SECONDS[mix["kind"]])
                   if args.trace else None)
-    ctx = Context(args.workload, spec, mix, args.seed, args.seconds, mode,
-                  dev, trace_from, tracer, CompileLog())
     import importlib
     runner = importlib.import_module(f"chipbench.{KINDS[mix['kind']]}")
-    out = runner.run(ctx)
+    from repro import obs
+    with (obs.capture(annotate=True) if args.trace
+          else contextlib.nullcontext()) as rec:
+        ctx = Context(args.workload, spec, mix, args.seed, args.seconds,
+                      mode, dev, trace_from, tracer, CompileLog(), rec=rec)
+        if tracer is not None:
+            tracer.on_edge = ctx.snapshot
+        out = runner.run(ctx)
 
     from chipbench import correctness
     ok, checks = correctness.judge(out["readings"],
@@ -218,11 +255,21 @@ def main(argv=None, *, root: str = ROOT, require_chip: bool = True,
               "attempted": out["attempted"], "failed": out["failed"]}
     metrics = {}
     if args.trace:
-        from chipbench import trace_reduce
+        from chipbench import spans, trace_reduce
         tr = trace_reduce.reduce(trace_reduce.find_file(trace_dir))
         shutil.rmtree(trace_dir, ignore_errors=True)
         device.update(busy_s=tr.busy_s, window_s=tr.window_s)
-        run = Run(spec, peak, out["window"], tr, n_compiles)
+        counters = {"window": ctx.counters("open", "close"),
+                    "traced": ctx.counters("trace_start", "trace_stop")}
+        log(spans.longest(rec, STEP_SPANS[mix["kind"]], ctx.t_window,
+                          ctx.t_closed))
+        if mix["kind"] == "serve_open_loop":
+            log(spans.kv_summary(ctx.counters_at.get("open", {}),
+                                 ctx.counters_at.get("close", {}),
+                                 spec.raw["serve"]["page_size"]))
+        log(f"idle by program span: {tr.idle_by_span}")
+        run = Run(spec, peak, out["window"], tr, n_compiles, counters, rec,
+                  ctx.t_window, ctx.t_closed)
         for m in cell.per_layer:
             value = bench_spec.metric_reader(root, m["name"])(run)
             if value is not None:

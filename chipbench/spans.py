@@ -5,25 +5,16 @@ The program names its jitted serving programs (``decode_step_paged``,
 tells them apart: ``executions`` gives the device time of each execution
 of one of them from the reduced trace alone.
 
-Under ``obs.capture(annotate=True)`` the program's spans (``engine.*``,
-``trainer.*``) are also host events of the profiler trace, named with
-``PREFIX``. ``reduce`` reads them beside the device ops: it labels each
-idle gap "harness annotation / program span / runtime event" and sums the
-device's idle time by the innermost program span. The readers below it
-take the recorder of such a capture: the engine's request phases
-(``engine.request.*`` intervals) and the trainer's ``trainer.data`` spans.
-``tools/span_run.py`` runs a cell so.
+A traced run (``run.py --trace 1``) runs its cell under
+``obs.capture(annotate=True)``; ``trace_reduce`` places the program's
+spans in the profiler trace beside the device ops. The readers below take
+that capture's recorder (``Run.rec``): the engine's request phases
+(``engine.request.*`` intervals), the trainer's ``trainer.data`` spans, the
+longest step, and the KV pool from the counters.
 """
 from __future__ import annotations
 
-import dataclasses
-
 import numpy as np
-
-from . import trace_reduce
-
-PREFIX = "repro."           # the program's spans in a profiler trace
-OUTSIDE = "outside the program spans"
 
 
 def executions(trace, program: str) -> list:
@@ -49,73 +40,6 @@ def executions(trace, program: str) -> list:
 
 def mean_ms(seconds: list):
     return 1e3 * sum(seconds) / len(seconds) if seconds else None
-
-
-@dataclasses.dataclass
-class SpanTrace:
-    spans: list             # (start_ns, end_ns, name, rid) in the window
-    gaps: list              # [label, seconds], longest first
-    idle_by_span: dict      # innermost program span -> idle seconds
-
-
-def _inner(events, t: float):
-    """Name of the innermost event (latest start) holding ``t``."""
-    best = None
-    for ev in events:
-        if ev[0] <= t <= ev[1] and (best is None or ev[0] >= best[0]):
-            best = ev
-    return best[2] if best else None
-
-
-def reduce(path: str, trace, n_gaps: int = 10) -> SpanTrace | None:
-    """The program spans of the profile at ``path`` against ``trace``
-    (``trace_reduce.reduce`` of the same file); None when the profile
-    holds no program span."""
-    window, own, program, runtime = None, [], [], []
-    for plane in trace_reduce.load(path).planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            for ev in line.events:
-                s, e = ev.start_ns, ev.start_ns + ev.duration_ns
-                if ev.name == trace_reduce.WINDOW:
-                    window = (s, e)
-                elif ev.name.startswith(trace_reduce.OWN_PREFIX):
-                    own.append((s, e, ev.name))
-                elif ev.name.startswith(PREFIX):
-                    program.append((s, e, ev.name[len(PREFIX):],
-                                    dict(ev.stats).get("rid")))
-                elif not ev.name.startswith("$"):
-                    runtime.append((s, e, ev.name))
-    if window is None or not program:
-        return None
-    w0, w1 = window
-    program = sorted(sp for sp in program if sp[1] > w0 and sp[0] < w1)
-    busy = trace_reduce._union([(op.start_ns, op.start_ns + op.dur_ns)
-                                for op in trace.ops])
-    gaps, prev = [], w0
-    for s, e in busy + [[w1, w1]]:
-        if s > prev:
-            gaps.append((prev, min(s, w1)))
-        prev = max(prev, e)
-    idle: dict = {}
-    for s, e in gaps:
-        inside = [sp for sp in program if sp[1] > s and sp[0] < e]
-        cuts = sorted({s, e} | {t for sp in inside for t in sp[:2]
-                                if s < t < e})
-        for a, b in zip(cuts, cuts[1:]):
-            name = _inner(inside, (a + b) / 2) or OUTSIDE
-            idle[name] = idle.get(name, 0.0) + (b - a) * 1e-9
-    gaps.sort(key=lambda g: g[0] - g[1])
-    labelled = []
-    for s, e in gaps[:n_gaps]:
-        mid = (s + e) / 2
-        parts = (_inner(own, mid) or "outside the harness's annotations",
-                 _inner(program, mid) or OUTSIDE,
-                 _inner(runtime, mid) or "host Python")
-        labelled.append([" / ".join(parts), (e - s) * 1e-9])
-    return SpanTrace(program, labelled,
-                     dict(sorted(idle.items(), key=lambda kv: -kv[1])))
 
 
 # -- readers of a capture's recorder ---------------------------------------

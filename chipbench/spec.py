@@ -1,13 +1,17 @@
 """BENCHMARK.json and the files it names, found by name: a cell's
 configuration is ``configs/<config>.json`` (via the entry's ``file``), its
-traffic ``traffic/<traffic>.json``, its limits ``cells/<workload>.json``
-and each per-layer metric ``metrics/<metric>.py``."""
+model family ``families/<family>.py`` (the configuration's ``family``
+key), its traffic ``traffic/<traffic>.json``, its limits
+``cells/<workload>.json`` and each per-layer metric
+``metrics/<metric>.py``."""
 from __future__ import annotations
 
 import dataclasses
 import importlib.util
 import json
 import os
+import re
+import sys
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -52,12 +56,28 @@ def cell(root: str, name: str) -> Cell:
                 int(w["chips"]), e2e, per)
 
 
+_MODULES: dict = {}
+
+
+def _module(root: str, kind: str, name: str):
+    """``<root>/chipbench/<kind>/<name>.py``, loaded once."""
+    path = os.path.join(root, os.path.basename(HERE), kind, f"{name}.py")
+    if path not in _MODULES:
+        mod_name = f"chipbench_{kind}_" + re.sub(r"\W", "_", name)
+        mod_spec = importlib.util.spec_from_file_location(mod_name, path)
+        mod = importlib.util.module_from_spec(mod_spec)
+        sys.modules[mod_name] = mod
+        mod_spec.loader.exec_module(mod)
+        _MODULES[path] = mod
+    return _MODULES[path]
+
+
 def metric_reader(root: str, name: str):
     """The ``read(run)`` function of ``metrics/<name>.py``."""
-    path = os.path.join(root, os.path.basename(HERE), "metrics",
-                        f"{name}.py")
-    mod_spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return _module(root, "metrics", name).read
+
+
+def family(root: str, name: str):
+    """The module ``families/<name>.py``: everything the harness knows of
+    one model architecture (``families/dense.py`` lists what it holds)."""
+    return _module(root, "families", name)

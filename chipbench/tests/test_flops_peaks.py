@@ -4,6 +4,7 @@ import os
 import pytest
 
 from chipbench import flops, model_spec, peaks
+from chipbench.model_spec import family
 from chipbench.tests.tree import REPO
 
 
@@ -24,13 +25,13 @@ def test_peaks_by_device_kind():
 def test_granite_matmul_params_and_head():
     g = _spec("granite-8b")
     # 4096 (4096 + 2 1024) + 4096^2 + 3 4096 14336
-    assert flops.matmul_params(g) == 25165824 + 16777216 + 176160768
+    assert family(g).matmul_params(g) == 25165824 + 16777216 + 176160768
     assert flops.head_params(g) == 4096 * 49152
 
 
 def test_minicpm_train_flops_per_token():
     m = _spec("minicpm-2b", layers=6)
-    assert flops.matmul_params(m) == 15925248 + 5308416 + 39813120
+    assert family(m).matmul_params(m) == 15925248 + 5308416 + 39813120
     n = 6 * 61046784 + 2304 * 122753
     attn = 3 * 4 * 36 * 64 * 6 * (4097 / 2)
     assert flops.train_flops_per_token(m, 4096) == pytest.approx(

@@ -3,11 +3,10 @@
 hand-built traces, the span readers on a hand-built window and recorder,
 the reduction on a trace recorded on one TPU v5e with the spans on
 (``tools/record_span_fixture.py``: granite-8b at one layer serving three
-requests with 512-token chunks), and ``tools/span_run.py`` on the CPU."""
+requests with 512-token chunks), and ``run.py --trace 1`` on the CPU,
+with a metric file of a temporary tree reading a program counter."""
 import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -134,12 +133,11 @@ def test_trainer_and_engine_summaries():
 
 @pytest.fixture(scope="module")
 def recorded():
-    tr = trace_reduce.reduce(SPANS)
-    return tr, spans.reduce(SPANS, tr)
+    return trace_reduce.reduce(SPANS)
 
 
 def test_fixture_module_executions_match_the_modules_line(recorded):
-    tr, _ = recorded
+    tr = recorded
     modules = {}
     for plane in trace_reduce.load(SPANS).planes:
         for line in plane.lines:
@@ -161,66 +159,98 @@ def test_fixture_module_executions_match_the_modules_line(recorded):
 
 
 def test_fixture_gaps_name_program_spans(recorded):
-    tr, st = recorded
-    assert st is not None and st.spans
-    assert {name for _, _, name, _ in st.spans} >= {
+    tr = recorded
+    assert tr.spans
+    assert {name for _, _, name, _ in tr.spans} >= {
         "engine.step", "engine.admit", "engine.prefill_chunk",
         "engine.grow", "engine.decode_launch", "engine.sample",
         "engine.retire"}
-    assert {rid for _, _, name, rid in st.spans
+    assert {rid for _, _, name, rid in tr.spans
             if name == "engine.prefill_chunk"} == {10, 11, 12}
-    assert len(st.gaps) == 10
-    for label, seconds in st.gaps:
+    assert len(tr.gaps) == 10
+    for label, seconds in tr.gaps:
         harness, program, runtime = label.split(" / ")
         assert seconds > 0 and runtime
-        assert program == spans.OUTSIDE or program.startswith("engine.")
-    # the same gaps as the plain reduction, with the program span between
-    assert [g for _, g in st.gaps] == pytest.approx([g for _, g in tr.gaps])
-    assert any(label.split(" / ")[1] != spans.OUTSIDE
-               for label, _ in st.gaps)
+        assert program == trace_reduce.OUTSIDE or program.startswith(
+            "engine.")
+    # the longest gaps of the window, with the program span between
+    assert sum(g for _, g in tr.gaps) <= tr.window_s - tr.busy_s + 1e-9
+    assert [g for _, g in tr.gaps] == sorted((g for _, g in tr.gaps),
+                                             reverse=True)
+    assert any(label.split(" / ")[1] != trace_reduce.OUTSIDE
+               for label, _ in tr.gaps)
 
 
 def test_fixture_idle_by_span_adds_up_to_idle(recorded):
-    tr, st = recorded
-    assert sum(st.idle_by_span.values()) == pytest.approx(
+    tr = recorded
+    assert sum(tr.idle_by_span.values()) == pytest.approx(
         tr.window_s - tr.busy_s, rel=1e-9, abs=1e-9)
-    assert all(s > 0 for s in st.idle_by_span.values())
-    top = next(iter(st.idle_by_span))
+    assert all(s > 0 for s in tr.idle_by_span.values())
+    top = next(iter(tr.idle_by_span))
     assert top.startswith("engine.")
-    # a trace without program spans has nothing to add
-    plain = os.path.join(DATA, "serve.xplane.pb.gz")
-    assert spans.reduce(plain, trace_reduce.reduce(plain)) is None
+    # a trace without program spans puts every idle second outside them
+    plain = trace_reduce.reduce(os.path.join(DATA, "serve.xplane.pb.gz"))
+    assert plain.spans == []
+    assert list(plain.idle_by_span) == [trace_reduce.OUTSIDE]
+    assert plain.idle_by_span[trace_reduce.OUTSIDE] == pytest.approx(
+        plain.window_s - plain.busy_s, rel=1e-9, abs=1e-9)
 
 
-SPAN_RUN = """
-import json, sys
-sys.path[:0] = [{repo!r}, {src!r}]
-from chipbench.tools import span_run
-sys.exit(span_run.main({argv!r}, root={root!r}, require_chip=False,
-                       mode="reference"))
+COUNTER_METRIC = """
+def read(run):
+    walked = run.counters["window"].get("engine.kv.blocks_walked")
+    return float(walked) if walked else None
 """
+_TRACED: dict = {}
+
+
+def _traced(tmp_path_factory, workload):
+    """``run.main --trace 1`` of a tiny cell on the CPU, in a tree whose
+    ``metrics/`` gains ``kv_blocks_walked.py``, a reader of a program
+    counter, entered for the serving cell; the metrics that need the
+    chip's peaks are left out."""
+    if workload not in _TRACED:
+        root = tree.build(str(tmp_path_factory.mktemp("traced")))
+        tree.add_files(root, "metrics",
+                       {"kv_blocks_walked.py": COUNTER_METRIC})
+        path = os.path.join(root, "BENCHMARK.json")
+        with open(path) as fh:
+            bench = json.load(fh)
+        bench["per_layer"] = [m for m in bench["per_layer"]
+                              if not m["name"].startswith("mfu.")]
+        bench["per_layer"].append({
+            "name": "kv_blocks_walked", "unit": "count", "better": "lower",
+            "source": "program_counter", "layer": "engine",
+            "moves": "itl_p95_ms", "workloads": ["tiny-lm.chat"]})
+        with open(path, "w") as fh:
+            json.dump(bench, fh)
+        _TRACED[workload] = tree.run_cell(root, workload,
+                                          seed=2 ** 33 + 5, trace=1,
+                                          timeout=600)
+    return _TRACED[workload]
 
 
 @pytest.mark.parametrize("workload,names", [
     ("tiny-lm.chat", {"engine_queue_p90_ms", "prefill_p90_ms"}),
     ("tiny-cpm.train", {"data_wait_ms.train"})])
-def test_span_run_on_cpu(tmp_path, workload, names):
-    root = tree.build(str(tmp_path))
-    code = SPAN_RUN.format(
-        repo=tree.REPO, src=os.path.join(tree.REPO, "src"), root=root,
-        argv=["--workload", workload, "--seed", str(2 ** 33 + 5),
-              "--seconds", "2"])
-    p = subprocess.run([sys.executable, "-c", code], cwd=root,
-                       env=dict(os.environ, JAX_PLATFORMS="cpu"),
-                       capture_output=True, text=True, timeout=600)
-    assert p.returncode == 0, p.stderr[-3000:]
-    res = json.loads(p.stdout.strip().splitlines()[-1])
+def test_span_run_on_cpu(tmp_path_factory, workload, names):
+    rc, res, err = _traced(tmp_path_factory, workload)
+    assert rc == 0, err[-3000:]
     assert res["correct"] is True
-    assert set(res["metrics"]) == names
-    assert all(v is not None and v >= 0 for v in res["metrics"].values())
+    assert names <= set(res["metrics"])
+    assert all(res["metrics"][n]["value"] >= 0 for n in names)
     gaps = res["breakdown"]["idle_gaps"]
     assert gaps and all(len(label.split(" / ")) == 3 for label, _ in gaps)
-    assert any(k.startswith(("engine.", "trainer."))
-               for k in res["breakdown"]["idle_by_span"])
+    by_span = err.split("idle by program span: ", 1)[1].splitlines()[0]
+    assert "'engine." in by_span or "'trainer." in by_span
     step = "engine.step" if "chat" in workload else "trainer.step"
-    assert f"longest {step} " in p.stderr
+    assert f"longest {step} " in err
+
+
+def test_counter_metric_from_added_file(tmp_path_factory):
+    rc, res, err = _traced(tmp_path_factory, "tiny-lm.chat")
+    assert rc == 0, err[-3000:]
+    walked = res["metrics"]["kv_blocks_walked"]
+    assert walked["unit"] == "count" and walked["value"] >= 1
+    # the engine's own count over the window, as the KV line reports it
+    assert "KV pool: " in err

@@ -1,7 +1,9 @@
 """A benchmark tree of tiny cells for CPU tests: BENCHMARK.json, configs,
-traffic mixes and limits written into a temporary directory, with ``src``
-linked to the repository's, so ``run.main(root=...)`` finds everything by
-name there."""
+traffic mixes and limits written into a temporary directory, with ``src``,
+``metrics/`` and ``families/`` linked to the repository's, so
+``run.main(root=...)`` finds everything by name there. ``add_files`` lays a
+real directory of links in place of one of those, with files of its own
+beside them."""
 from __future__ import annotations
 
 import json
@@ -87,10 +89,26 @@ def build(root: str) -> str:
     for w, lim in LIMITS.items():
         _dump(os.path.join(b, "cells", f"{w}.json"),
               {"limits": {k: {"limit": v} for k, v in lim.items()}})
-    os.symlink(os.path.join(REPO, "chipbench", "metrics"),
-               os.path.join(b, "metrics"))
+    for kind in ("metrics", "families"):
+        os.symlink(os.path.join(REPO, "chipbench", kind),
+                   os.path.join(b, kind))
     os.symlink(os.path.join(REPO, "src"), os.path.join(root, "src"))
     return root
+
+
+def add_files(root: str, kind: str, files: dict) -> None:
+    """Make ``chipbench/<kind>/`` of the tree a directory holding links to
+    the repository's files and ``files`` ({name: text}) of its own."""
+    d = os.path.join(root, "chipbench", kind)
+    os.unlink(d)
+    os.makedirs(d)
+    src = os.path.join(REPO, "chipbench", kind)
+    for name in os.listdir(src):
+        if name.endswith(".py"):
+            os.symlink(os.path.join(src, name), os.path.join(d, name))
+    for name, text in files.items():
+        with open(os.path.join(d, name), "w") as fh:
+            fh.write(text)
 
 
 RUNNER = """
@@ -104,11 +122,12 @@ sys.exit(run.main({argv!r}, root={root!r}, require_chip=False,
 
 
 def run_cell(root: str, workload: str, *, seed: int = 2 ** 33 + 7,
-             seconds: float = 2.0, prelude: str = "", timeout=300):
+             seconds: float = 2.0, prelude: str = "", timeout=300,
+             trace: int = 0):
     """Run one tiny cell in a fresh CPU process; returns (rc, the parsed
     last stdout line or None, stderr)."""
     argv = ["--workload", workload, "--seed", str(seed), "--seconds",
-            str(seconds), "--trace", "0"]
+            str(seconds), "--trace", str(trace)]
     code = RUNNER.format(repo=REPO, src=os.path.join(REPO, "src"),
                          prelude=prelude, argv=argv, root=root)
     env = dict(os.environ, JAX_PLATFORMS="cpu")

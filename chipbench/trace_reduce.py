@@ -1,13 +1,20 @@
 """A profiler trace (``.xplane.pb``) reduced to what the metrics read:
 device busy time and idle share over the traced window, every device
-operation with its HLO text (which carries its shapes), and the longest
-idle gaps labelled by what the host was doing.
+operation with its HLO text (which carries its shapes), the program's
+spans, and the longest idle gaps labelled by what the host was doing.
 
 The traced window is the host event named ``WINDOW`` (a
 ``jax.profiler.TraceAnnotation`` the harness opens around the window);
 device and host events share the trace's clock. Device time is the union
 of the intervals of the ``XLA Ops`` line of each ``/device:`` plane,
 averaged over the devices that ran anything.
+
+Under ``obs.capture(annotate=True)`` (``run.py --trace 1``) the program's
+spans (``engine.*``, ``trainer.*``) are host events of the trace too,
+named with ``PREFIX``. Each idle gap is labelled "harness annotation /
+program span / runtime event", each the innermost one around the gap's
+middle, and the device's idle time is summed by the innermost program
+span (``idle_by_span``).
 """
 from __future__ import annotations
 
@@ -19,6 +26,8 @@ import re
 
 WINDOW = "chipbench.window"
 OWN_PREFIX = "chipbench."
+PREFIX = "repro."           # the program's spans (repro.obs)
+OUTSIDE = "outside the program spans"
 CONTAINERS = ("while", "conditional", "call")   # their time is their body's
 _NAME = re.compile(r"^%?([A-Za-z_][\w\-]*?)(?:\.\d+)?\s*=")
 _SHAPE = re.compile(r"\b(pred|s8|u8|s16|u16|s32|u32|s64|u64|f8e4m3fn|f8e5m2|"
@@ -78,7 +87,11 @@ class Trace:
     busy_s: float
     devices: int
     ops: list                   # Op inside the window (all devices)
-    gaps: list                  # (label, seconds), longest first
+    gaps: list                  # [label, seconds], longest first
+    spans: list = dataclasses.field(default_factory=list)
+    # (start_ns, end_ns, name, rid) of each program span in the window
+    idle_by_span: dict = dataclasses.field(default_factory=dict)
+    # innermost program span -> idle seconds, most first
 
     def kernel_calls(self, name: str) -> list:
         return [op for op in self.ops if op.is_kernel and op.name == name]
@@ -117,17 +130,32 @@ def _union(intervals: list) -> list:
     return merged
 
 
-def _label(mid: float, own: list, runtime: list) -> str:
-    """Innermost harness annotation and runtime event around ``mid``."""
-    def inner(events):
-        best = None
-        for s, e, name in events:
-            if s <= mid <= e and (best is None or s >= best[0]):
-                best = (s, name)
-        return best[1] if best else None
-    a = inner(own) or "outside the harness's annotations"
-    r = inner(runtime)
-    return f"{a} / {r}" if r else f"{a} / host Python"
+def _inner(events, t: float):
+    """Name of the innermost event (latest start) holding ``t``."""
+    best = None
+    for ev in events:
+        if ev[0] <= t <= ev[1] and (best is None or ev[0] >= best[0]):
+            best = ev
+    return best[2] if best else None
+
+
+def _label(mid: float, own: list, program: list, runtime: list) -> str:
+    return " / ".join((
+        _inner(own, mid) or "outside the harness's annotations",
+        _inner(program, mid) or OUTSIDE,
+        _inner(runtime, mid) or "host Python"))
+
+
+def _idle_by_span(gaps: list, program: list) -> dict:
+    idle: dict = {}
+    for s, e in gaps:
+        inside = [sp for sp in program if sp[1] > s and sp[0] < e]
+        cuts = sorted({s, e} | {t for sp in inside for t in sp[:2]
+                                if s < t < e})
+        for a, b in zip(cuts, cuts[1:]):
+            name = _inner(inside, (a + b) / 2) or OUTSIDE
+            idle[name] = idle.get(name, 0.0) + (b - a) * 1e-9
+    return dict(sorted(idle.items(), key=lambda kv: -kv[1]))
 
 
 def load(path: str):
@@ -142,7 +170,7 @@ def load(path: str):
 
 def reduce(path: str, n_gaps: int = 10) -> Trace:
     pd = load(path)
-    window, own, runtime = None, [], []
+    window, own, program, runtime = None, [], [], []
     device_lines = []
     for plane in pd.planes:
         if plane.name.startswith("/device:"):
@@ -159,6 +187,9 @@ def reduce(path: str, n_gaps: int = 10) -> Trace:
                         window = span[:2]
                     elif ev.name.startswith(OWN_PREFIX):
                         own.append(span)
+                    elif ev.name.startswith(PREFIX):
+                        program.append((*span[:2], ev.name[len(PREFIX):],
+                                        dict(ev.stats).get("rid")))
                     elif not ev.name.startswith("$"):
                         runtime.append(span)
     if window is None:
@@ -192,8 +223,11 @@ def reduce(path: str, n_gaps: int = 10) -> Trace:
         if s > prev:
             gaps.append((prev, s))
         prev = max(prev, e)
+    program = sorted((sp for sp in program if sp[1] > w0 and sp[0] < w1),
+                     key=lambda sp: sp[:2])
+    idle = _idle_by_span(gaps, program)
     gaps.sort(key=lambda g: g[0] - g[1])
-    labelled = [[_label((s + e) / 2, own, runtime), (e - s) * 1e-9]
+    labelled = [[_label((s + e) / 2, own, program, runtime), (e - s) * 1e-9]
                 for s, e in gaps[:n_gaps]]
     return Trace((w1 - w0) * 1e-9, busy * 1e-9 / devices, devices, ops,
-                 labelled)
+                 labelled, program, idle)

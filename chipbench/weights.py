@@ -1,14 +1,16 @@
 """Model weights made from the seed, on the device, in one jitted call.
 
-The layout is the benchmark's own, independent of the program's:
+The layout is the benchmark's own, independent of the program's: the
+leaves every family has,
 
     embed (V, d), lm_head (d, V) unless tied, final_norm (d,)
-    layers: wqk (L, d, (H + Hkv) * hd)   q then k columns
-            wv (L, d, Hkv * hd), wo (L, H * hd, d)
-            w_gate, w_up (L, d, f), w_down (L, f, d)
-            ln1, ln2 (L, d)
 
-Matrices are normal with std 1/sqrt(fan-in); an untied embedding has unit
+and the family's stacked per-layer leaves (``families/<family>.py``'s
+``shapes``), each with the layers as its first axis and any rank after it.
+
+Matrices are normal with std 1/sqrt(fan-in), the fan-in being the
+second-to-last side of one layer's slice (d for a (d, f) matrix, an
+(E, d, f) expert stack or a (d, E) router); an untied embedding has unit
 std, a tied one 1/sqrt(d) (it is also the LM head); norm scales are
 1 + 0.1 * normal, so a path that drops a norm's scale shows. Each stacked
 leaf is drawn one layer at a time, so no leaf ever holds an int32 copy of
@@ -23,6 +25,8 @@ import jax.numpy as jnp
 
 from . import model_spec
 
+UNSTACKED = ("embed", "final_norm", "lm_head")
+
 
 def seed_key(seed: int, stream: int = 0) -> jax.Array:
     """A threefry key from any whole number (beyond 32 bits too)."""
@@ -30,25 +34,18 @@ def seed_key(seed: int, stream: int = 0) -> jax.Array:
     return jax.random.wrap_key_data(jnp.asarray(words, jnp.uint32))
 
 
+def common(spec: model_spec.ModelSpec) -> dict:
+    """The leaves every family has: {name: (shape, kind)}."""
+    out = {"embed": ((spec.vocab, spec.d), "embed"),
+           "final_norm": ((spec.d,), "norm")}
+    if not spec.tied:
+        out["lm_head"] = ((spec.d, spec.vocab), "matrix")
+    return out
+
+
 def shapes(spec: model_spec.ModelSpec) -> dict:
     """{name: (shape, kind)}; kind is 'matrix', 'embed' or 'norm'."""
-    d, f, L = spec.d, spec.f, spec.layers
-    q, kv = spec.heads * spec.hd, spec.kv_heads * spec.hd
-    out = {
-        "embed": ((spec.vocab, d), "embed"),
-        "final_norm": ((d,), "norm"),
-        "wqk": ((L, d, q + kv), "matrix"),
-        "wv": ((L, d, kv), "matrix"),
-        "wo": ((L, q, d), "matrix"),
-        "w_gate": ((L, d, f), "matrix"),
-        "w_up": ((L, d, f), "matrix"),
-        "w_down": ((L, f, d), "matrix"),
-        "ln1": ((L, d), "norm"),
-        "ln2": ((L, d), "norm"),
-    }
-    if not spec.tied:
-        out["lm_head"] = ((d, spec.vocab), "matrix")
-    return out
+    return model_spec.family(spec).shapes(spec)
 
 
 def _draw(key, shape, kind, dtype, spec):
@@ -64,9 +61,10 @@ def _draw(key, shape, kind, dtype, spec):
 
 def leaf(spec, name: str, key, dtype):
     """One leaf; a stacked leaf is drawn layer by layer under lax.map."""
-    shape, kind = shapes(spec)[name]
-    k = jax.random.fold_in(key, sorted(shapes(spec)).index(name))
-    if name in ("embed", "final_norm", "lm_head"):
+    table = shapes(spec)
+    shape, kind = table[name]
+    k = jax.random.fold_in(key, sorted(table).index(name))
+    if name in UNSTACKED:
         return _draw(k, shape, kind, dtype, spec)
     layer_keys = jax.vmap(lambda i: jax.random.fold_in(k, i))(
         jnp.arange(shape[0]))
@@ -80,4 +78,3 @@ def make(spec: model_spec.ModelSpec, seed: int, dtype=None) -> dict:
     names = sorted(shapes(spec))
     fn = jax.jit(lambda key: {n: leaf(spec, n, key, dtype) for n in names})
     return fn(seed_key(seed))
-
